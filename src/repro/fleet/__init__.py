@@ -64,7 +64,6 @@ from .elastic_bridge import (  # noqa: F401
     MigrationPhases,
     SimulatedElasticBackend,
     SnapshotInfo,
-    auto_backend,
     execute_move,
 )
 from .executor import (  # noqa: F401

@@ -32,10 +32,11 @@ Backends:
   the paper scenarios' fleet fingerprints are bit-identical to
   `FlatStateBackend` — the bridge changes what the numbers *mean*, not
   what happens, until a job declares real state.
-* `LiveElasticBackend` — the real thing, used when JAX devices are
-  present: `ckpt.save` on snapshot, `reshard_restore` onto the rebuilt
-  mesh on restore, source-checkpoint re-install on rollback.  Drives the
-  demo (`examples/reconfiguration_demo.py`) and the multi-device smoke.
+* `LiveElasticBackend` — the real thing: `ckpt.save` on snapshot,
+  `reshard_restore` onto the rebuilt mesh on restore, source-checkpoint
+  re-install on rollback.  Drives the demo
+  (`examples/reconfiguration_demo.py`), the multi-device smoke and the
+  chip smoke (`chip_smoke.py`).
 
 Rollback contract: when a destination dies mid-copy the executor calls
 `ElasticBackend.rollback` — the source checkpoint taken at transfer start
@@ -497,13 +498,3 @@ def execute_move(backend: ElasticBackend, request: PlacementRequest,
                            transfer_s=transfer_s, restore_s=restore_s,
                            downtime_s=downtime, mbits=snap.mbits)
 
-
-def auto_backend(state_mb: float = 64.0) -> ElasticBackend:
-    """`LiveElasticBackend` when JAX devices are usable (the demo / real
-    deployments), `SimulatedElasticBackend` otherwise (headless sims)."""
-    try:
-        import jax
-        jax.devices()
-    except Exception:
-        return SimulatedElasticBackend(default_state_mb=state_mb)
-    return LiveElasticBackend()

@@ -3,8 +3,8 @@ KV cache (decode_32k / long_500k serve cells).
 
 Grid = (B·Hkv, Sk/block_k); per program, the G grouped q-heads of one kv
 head attend to one KV block with (m, l, acc) scratch carried across the
-sequential k dimension.  The valid prefix length (per batch row) arrives as
-an SMEM scalar block; everything past it is masked.
+sequential k dimension.  The valid prefix length of every row arrives as a
+scalar-prefetch operand (SMEM, whole array); everything past it is masked.
 """
 
 from __future__ import annotations
@@ -29,7 +29,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    kv_len = len_ref[0]
+    kv_len = len_ref[pl.program_id(0)]
     run = kj * block_k < kv_len  # skip fully-invalid blocks
 
     @pl.when(run)
@@ -80,20 +80,22 @@ def decode_attention(
     kernel = functools.partial(_kernel, scale=D ** -0.5, block_k=block_k, n_k=n_k)
     out = pl.pallas_call(
         kernel,
-        grid=(B * Hkv, n_k),
-        in_specs=[
-            pl.BlockSpec((1,), lambda h, j: (h,), memory_space=pltpu.SMEM),
-            pl.BlockSpec((None, G, D), lambda h, j: (h, 0, 0)),
-            pl.BlockSpec((None, block_k, D), lambda h, j: (h, j, 0)),
-            pl.BlockSpec((None, block_k, D), lambda h, j: (h, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, G, D), lambda h, j: (h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B * Hkv, n_k),
+            in_specs=[
+                pl.BlockSpec((None, G, D), lambda h, j, lens: (h, 0, 0)),
+                pl.BlockSpec((None, block_k, D), lambda h, j, lens: (h, j, 0)),
+                pl.BlockSpec((None, block_k, D), lambda h, j, lens: (h, j, 0)),
+            ],
+            out_specs=pl.BlockSpec((None, G, D), lambda h, j, lens: (h, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((G,), jnp.float32),
+                pltpu.VMEM((G,), jnp.float32),
+                pltpu.VMEM((G, D), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((B * Hkv, G, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
-        ],
         interpret=interpret,
     )(lens, qf, kf, vf)
     return out.reshape(B, 1, Hq, D)

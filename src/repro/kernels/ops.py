@@ -1,10 +1,11 @@
 """Jit'd kernel entry points with backend dispatch.
 
-On TPU the Pallas kernels compile natively (``interpret=False``); elsewhere
-they run in interpret mode, which executes the kernel body op-by-op on CPU —
-bitwise the same program structure, so correctness tests on CPU validate
-the TPU kernel logic.  Model code (`cfg.attn_impl`/`cfg.ssm_impl`) routes
-here when the kernels are enabled.
+On TPU the Pallas kernels compile natively (``interpret=False``).  On the
+CPU backend (the test suite, ``JAX_PLATFORMS=cpu``) they run in interpret
+mode, which executes the kernel body op-by-op — the same program
+structure, so correctness tests on CPU validate the TPU kernel logic.  Any
+other backend is refused rather than silently interpreted.  Model code
+(`cfg.attn_impl`/`cfg.ssm_impl`) routes here when the kernels are enabled.
 """
 
 from __future__ import annotations
@@ -21,7 +22,13 @@ from . import ssm_scan as _ssm
 
 @functools.cache
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"Pallas kernels run natively on TPU or interpreted on "
+                       f"CPU; backend {backend!r} has neither path")
 
 
 def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,

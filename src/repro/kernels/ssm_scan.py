@@ -6,8 +6,10 @@ once and each output exactly once — the jnp reference materializes
 (B, nc, L, L, H) decay tensors instead (the memory-term gap the §Perf log
 quantifies).
 
-Per program: x (L, P), B/C (L, N), dt (L,) for one (batch, head, chunk):
-intra-chunk quadratic form + state update, all in fp32 in VMEM.
+Per program: x (L, P), B/C (L, N), dt and the in-chunk cumulative log
+decay (L, 1) for one (batch, head, chunk): intra-chunk quadratic form +
+state update, all in fp32 in VMEM.  The cumulative decay is summed outside
+the kernel (Mosaic has no cumsum), with the reference's own expression.
 """
 
 from __future__ import annotations
@@ -20,7 +22,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(a_ref, d_ref, x_ref, b_ref, c_ref, dt_ref, y_ref, s_out_ref,
+def _kernel(d_ref, x_ref, b_ref, c_ref, dt_ref, cum_ref, y_ref, s_out_ref,
             state_ref, *, chunk: int, n_chunks: int):
     cj = pl.program_id(1)
 
@@ -28,34 +30,32 @@ def _kernel(a_ref, d_ref, x_ref, b_ref, c_ref, dt_ref, y_ref, s_out_ref,
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    A = a_ref[0]                                    # scalar (SMEM): -exp(A_log)
-    D = d_ref[0]
+    D = d_ref[pl.program_id(0)]                     # scalar (SMEM)
     x = x_ref[...].astype(jnp.float32)              # (L, P)
     Bm = b_ref[...].astype(jnp.float32)             # (L, N)
     Cm = c_ref[...].astype(jnp.float32)             # (L, N)
-    dt = dt_ref[...].astype(jnp.float32)            # (L, 1) → (L,)
-    dt = dt.reshape(chunk)
+    dt = dt_ref[...].astype(jnp.float32)            # (L, 1)
+    cum = cum_ref[...]                              # (L, 1) inclusive log decay
 
-    la = A * dt                                     # (L,) log decay
-    cum = jnp.cumsum(la)                            # inclusive
     # Intra-chunk weights w[i,j] = exp(cum_i − cum_j)·dt_j, j ≤ i.
-    diff = cum[:, None] - cum[None, :]
     ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    w = jnp.where(ii >= jj, jnp.exp(diff) * dt[None, :], 0.0)
+    w = jnp.where(ii >= jj, jnp.exp(cum - cum.T) * dt.T, 0.0)
     g = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())))   # (L, L) C_i·B_j
     y_intra = jax.lax.dot_general(g * w, x, (((1,), (0,)), ((), ())))
 
     # Inter-chunk from carried state: y_i += exp(cum_i)·C_i·S.
     S = state_ref[...]                              # (P, N)
-    y_inter = jnp.exp(cum)[:, None] * jax.lax.dot_general(
+    y_inter = jnp.exp(cum) * jax.lax.dot_general(
         Cm, S, (((1,), (1,)), ((), ())))            # (L, P)
     y_ref[...] = (y_intra + y_inter + D * x).astype(y_ref.dtype)
 
     # State update: S ← exp(cum_L)·S + Σ_j exp(cum_L − cum_j)·dt_j·x_j⊗B_j.
-    wL = jnp.exp(cum[-1] - cum) * dt                # (L,)
-    state_ref[...] = jnp.exp(cum[-1]) * S + jax.lax.dot_general(
-        x * wL[:, None], Bm, (((0,), (0,)), ((), ())))
+    # cum_L as a scalar: Mosaic cannot broadcast a (1, 1) vector to (P, N).
+    cum_last = jnp.sum(cum[chunk - 1:, :])
+    wL = jnp.exp(cum_last - cum) * dt               # (L, 1)
+    state_ref[...] = jnp.exp(cum_last) * S + jax.lax.dot_general(
+        x * wL, Bm, (((0,), (0,)), ((), ())))
 
     @pl.when(cj == n_chunks - 1)
     def _emit_state():
@@ -80,32 +80,36 @@ def ssm_scan(
     nc = S // chunk
     xf = x.transpose(0, 2, 1, 3).reshape(B * H, S, P)
     dtf = dt.transpose(0, 2, 1).reshape(B * H, S, 1)
-    A = jnp.tile(-jnp.exp(A_log.astype(jnp.float32)), B)             # (B*H,)
-    Df = jnp.tile(D.astype(jnp.float32), B)
+    dtc = dt.reshape(B, nc, chunk, H).astype(jnp.float32)
+    cum = jnp.cumsum(-jnp.exp(A_log.astype(jnp.float32)) * dtc, axis=2)
+    cumf = cum.reshape(B, S, H).transpose(0, 2, 1).reshape(B * H, S, 1)
+    Df = jnp.tile(D.astype(jnp.float32), B)                           # (B*H,)
 
     kernel = functools.partial(_kernel, chunk=chunk, n_chunks=nc)
     y, s_final = pl.pallas_call(
         kernel,
-        grid=(B * H, nc),
-        in_specs=[
-            pl.BlockSpec((1,), lambda g, c: (g,), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1,), lambda g, c: (g,), memory_space=pltpu.SMEM),
-            pl.BlockSpec((None, chunk, P), lambda g, c: (g, c, 0)),
-            pl.BlockSpec((None, chunk, N), lambda g, c: (g // H, c, 0)),
-            pl.BlockSpec((None, chunk, N), lambda g, c: (g // H, c, 0)),
-            pl.BlockSpec((None, chunk, 1), lambda g, c: (g, c, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, chunk, P), lambda g, c: (g, c, 0)),
-            pl.BlockSpec((None, P, N), lambda g, c: (g, 0, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,   # D: one scalar per (batch, head) row
+            grid=(B * H, nc),
+            in_specs=[
+                pl.BlockSpec((None, chunk, P), lambda g, c, d: (g, c, 0)),
+                pl.BlockSpec((None, chunk, N), lambda g, c, d: (g // H, c, 0)),
+                pl.BlockSpec((None, chunk, N), lambda g, c, d: (g // H, c, 0)),
+                pl.BlockSpec((None, chunk, 1), lambda g, c, d: (g, c, 0)),
+                pl.BlockSpec((None, chunk, 1), lambda g, c, d: (g, c, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((None, chunk, P), lambda g, c, d: (g, c, 0)),
+                pl.BlockSpec((None, P, N), lambda g, c, d: (g, 0, 0)),
+            ],
+            scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((B * H, S, P), x.dtype),
             jax.ShapeDtypeStruct((B * H, P, N), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
-    )(A, Df, xf, Bm, Cm, dtf)
+    )(Df, xf, Bm, Cm, dtf, cumf)
     y = y.reshape(B, H, S, P).transpose(0, 2, 1, 3)
     state = s_final.reshape(B, H, P, N)
     return y, state

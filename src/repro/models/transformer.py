@@ -203,20 +203,46 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, cross_len: int = 0,
     return cache
 
 
+#: Batch axis of each per-slot cache group (scanned groups lead with the
+#: stacked layer axis).
+_SLOT_AXIS = {"blocks": 1, "tail": 0, "shared": 1, "tail_shared": 0}
+
+
+def _slot_index(axis: int, slot):
+    return (slice(None),) * axis + (slot,)
+
+
+def read_slot(cache: Dict, slot) -> Dict:
+    """One batch slot's state across the whole per-slot cache
+    (``init_cache(..., per_slot_index=True)``): its write offset and every
+    KV / recurrent leaf."""
+    out = {"index": cache["index"][slot]}
+    for key, axis in _SLOT_AXIS.items():
+        if key in cache:
+            out[key] = jax.tree.map(lambda x: x[_slot_index(axis, slot)],
+                                    cache[key])
+    return out
+
+
+def write_slot(cache: Dict, slot, state: Dict) -> Dict:
+    """Overwrite one batch slot with a `read_slot` payload.  Jitted with
+    the cache donated, this is an in-place per-slot update."""
+    out = dict(cache)
+    out["index"] = cache["index"].at[slot].set(state["index"])
+    for key, axis in _SLOT_AXIS.items():
+        if key in cache:
+            out[key] = jax.tree.map(
+                lambda x, v: x.at[_slot_index(axis, slot)].set(v),
+                cache[key], state[key])
+    return out
+
+
 def reset_slot(cache: Dict, slot) -> Dict:
     """Zero one batch slot across the whole cache (continuous batching:
     recurrent SSM/xLSTM states carry no positional mask, so a freed slot
     must be wiped before admitting a new request)."""
-    out = dict(cache)
-    out["index"] = cache["index"].at[slot].set(0)
-    out["blocks"] = jax.tree.map(lambda x: x.at[:, slot].set(0), cache["blocks"])
-    out["tail"] = jax.tree.map(lambda x: x.at[slot].set(0), cache["tail"])
-    if "shared" in cache:
-        out["shared"] = jax.tree.map(lambda x: x.at[:, slot].set(0), cache["shared"])
-    if "tail_shared" in cache:
-        out["tail_shared"] = jax.tree.map(lambda x: x.at[slot].set(0),
-                                          cache["tail_shared"])
-    return out
+    return write_slot(cache, slot,
+                      jax.tree.map(jnp.zeros_like, read_slot(cache, slot)))
 
 
 # --------------------------------------------------------------- forward --
@@ -343,28 +369,46 @@ def forward(
 
     # ------------------------------------------------------ scanned periods
     if layout.n_full:
+        # The stacked per-layer caches ride in the scan carry and each period
+        # writes its layer back in place, so a donated cache is updated
+        # without a second whole-cache buffer (as scan outputs it would be).
+        stacked = None
+        if cache is not None:
+            stacked = {"blocks": cache["blocks"]}
+            if layout.shared_attn:
+                stacked["shared"] = cache["shared"]
+
         def period_fn(carry, xs):
-            x, aux = carry
+            x, aux, stacked = carry
             x = constrain(x, ("dp", None, None))
             if cfg.bf16_cotangent:
                 x = bf16_cotangent_barrier(x)
-            block_slice, cache_slice, shared_cache = xs
+            block_slice, layer = xs
+            layer_cache = (None if stacked is None else
+                           jax.tree.map(lambda c: c[layer], stacked))
             if layout.shared_attn:
-                x, sc = _apply_shared(params["shared_attn"], x, cfg, positions,
-                                      shared_cache, index, rope_cache)
-            else:
-                sc = shared_cache
-            new_cslice = {}
+                x, sc = _apply_shared(
+                    params["shared_attn"], x, cfg, positions,
+                    None if layer_cache is None else layer_cache["shared"],
+                    index, rope_cache)
+            new_layer = {"blocks": {}}
             for j, kind in enumerate(layout.period_kinds):
-                cj = None if cache_slice is None else cache_slice[f"pos{j}"]
+                cj = (None if layer_cache is None
+                      else layer_cache["blocks"][f"pos{j}"])
                 x, cj_new, a = apply_block(
                     kind, block_slice[f"pos{j}"], x, cfg,
                     positions=positions, cache=cj, index=index,
                     encoder_out=encoder_out, rope_cache=rope_cache)
-                new_cslice[f"pos{j}"] = cj_new
+                new_layer["blocks"][f"pos{j}"] = cj_new
                 aux = aux + a
-            return (x, aux), (new_cslice if cache is not None else 0,
-                              sc if (cache is not None and layout.shared_attn) else 0)
+            if stacked is not None:
+                if layout.shared_attn:
+                    new_layer["shared"] = sc
+                stacked = jax.tree.map(
+                    lambda c, n: jax.lax.dynamic_update_index_in_dim(
+                        c, n.astype(c.dtype), layer, 0),
+                    stacked, new_layer)
+            return (x, aux, stacked), None
 
         body = period_fn
         if cfg.remat == "block":
@@ -376,17 +420,11 @@ def forward(
             body = jax.checkpoint(
                 period_fn, prevent_cse=False,
                 policy=jax.checkpoint_policies.dots_saveable)
-        xs = (
-            params["blocks"],
-            cache["blocks"] if cache is not None else None,
-            cache.get("shared") if (cache is not None and layout.shared_attn) else None,
-        )
-        (x, aux_total), (cache_out, shared_out) = jax.lax.scan(
-            body, (x, aux_total), xs, length=layout.n_full)
+        (x, aux_total, stacked), _ = jax.lax.scan(
+            body, (x, aux_total, stacked),
+            (params["blocks"], jnp.arange(layout.n_full)))
         if cache is not None:
-            new_cache["blocks"] = cache_out
-            if layout.shared_attn:
-                new_cache["shared"] = shared_out
+            new_cache.update(stacked)
 
     # --------------------------------------------------------- tail layers
     shared_i = 0

@@ -15,7 +15,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import ModelConfig, forward, init_cache, logits_fn
-from repro.models.transformer import encode, reset_slot
+from repro.models.transformer import encode, read_slot, reset_slot, write_slot
+
+# Per-slot cache updates run jitted with the cache donated, so admission and
+# kv-ship import rewrite one slot in place instead of copying every leaf.
+_read_slot = jax.jit(read_slot)
+_reset_slot = jax.jit(reset_slot, donate_argnums=(0,))
+_write_slot = jax.jit(write_slot, donate_argnums=(0,))
 
 
 def make_prefill_step(cfg: ModelConfig, max_len: int, cross_len: int = 0):
@@ -76,22 +82,29 @@ class ServeEngine:
     Finished sequences free their slot; queued requests are prefilling into
     freed slots (stop-the-world prefill — adequate for the example driver;
     the scheduler-level placement of *engines* is what the paper's technique
-    manages, see `core.cluster`)."""
+    manages, see `core.cluster`).
+
+    Params and cache live on ``device`` (default ``jax.devices()[0]``); the
+    decode step donates the cache, so only one copy of it is ever live."""
 
     def __init__(self, cfg: ModelConfig, params, batch_slots: int, max_len: int,
-                 eos_id: int = 0, temperature: float = 0.0, rng_seed: int = 0):
+                 eos_id: int = 0, temperature: float = 0.0, rng_seed: int = 0,
+                 device: Optional[jax.Device] = None):
         self.cfg = cfg
-        self.params = params
+        self.device = device if device is not None else jax.devices()[0]
+        self.params = jax.device_put(params, self.device)
         self.slots: List[Optional[Request]] = [None] * batch_slots
         self.max_len = max_len
         self.eos_id = eos_id
         self.temperature = temperature
         self.queue: List[Request] = []
         self.finished: List[Request] = []
-        self.cache = init_cache(cfg, batch_slots, max_len, per_slot_index=True)
+        self.cache = jax.jit(
+            lambda: init_cache(cfg, batch_slots, max_len, per_slot_index=True),
+            out_shardings=jax.sharding.SingleDeviceSharding(self.device))()
         # Per-slot write offsets (slot-local KV positions).
         self.offsets = np.zeros(batch_slots, np.int32)
-        self._decode = jax.jit(make_decode_step(cfg))
+        self._decode = jax.jit(make_decode_step(cfg), donate_argnums=(1,))
         self._base_key = jax.random.PRNGKey(rng_seed)
         self.steps = 0
 
@@ -115,7 +128,7 @@ class ServeEngine:
         self.offsets[slot] = 0
         # Reset the slot's write offset and recurrent states (stale KV is
         # masked by kv_len; SSM/xLSTM states must be zeroed explicitly).
-        self.cache = reset_slot(self.cache, slot)
+        self.cache = _reset_slot(self.cache, slot)
         req.output = []
 
     def _slot_tokens(self) -> np.ndarray:
@@ -130,14 +143,16 @@ class ServeEngine:
                 toks[i, 0] = req.output[-1] if req.output else self.eos_id
         return toks
 
-    def step(self) -> None:
-        # Fill free slots.
+    def step(self) -> Optional[jax.Array]:
+        """Admit queued requests into free slots and decode one token for
+        every occupied slot.  Returns the step's logits (slots, 1, vocab),
+        or None when there was nothing to decode."""
         for i, s in enumerate(self.slots):
             if s is None and self.queue:
                 self._admit(i, self.queue.pop(0))
         if all(s is None for s in self.slots):
-            return
-        tokens = jnp.asarray(self._slot_tokens())
+            return None
+        tokens = jax.device_put(self._slot_tokens(), self.device)
         self.cache, logits = self._decode(self.params, self.cache, tokens)
         self.steps += 1
         if self.temperature <= 0.0:
@@ -161,6 +176,7 @@ class ServeEngine:
                     req.done = True
                     self.finished.append(req)
                     self.slots[i] = None
+        return logits
 
     def run_until_done(self, max_steps: int = 10_000) -> List[Request]:
         while (self.queue or any(self.slots)) and self.steps < max_steps:
@@ -172,37 +188,19 @@ class ServeEngine:
     # helpers are the engine-level half of the fleet's kv-ship migration
     # strategy (repro.fleet.serving) — export on the source engine, import
     # into any free slot of a destination engine built from the same
-    # config/params, and decoding continues bit-identically.
+    # config/params (on this device or another), and decoding continues
+    # bit-identically.
     def export_slot(self, slot: int) -> Dict:
-        """Deep-copy one slot's KV/recurrent state + write offset."""
-        c = self.cache
-        state: Dict = {
-            "index": c["index"][slot],
-            "blocks": jax.tree.map(lambda x: x[:, slot], c["blocks"]),
-            "tail": jax.tree.map(lambda x: x[slot], c["tail"]),
-            "offset": int(self.offsets[slot]),
-        }
-        if "shared" in c:
-            state["shared"] = jax.tree.map(lambda x: x[:, slot], c["shared"])
-        if "tail_shared" in c:
-            state["tail_shared"] = jax.tree.map(lambda x: x[slot],
-                                                c["tail_shared"])
+        """Copy out one slot's KV/recurrent state + write offset."""
+        state = _read_slot(self.cache, slot)
+        state["offset"] = int(self.offsets[slot])
         return state
 
     def import_slot(self, slot: int, state: Dict) -> None:
-        """Install an `export_slot` payload into ``slot`` (overwrites it)."""
-        c = dict(self.cache)
-        c["index"] = self.cache["index"].at[slot].set(state["index"])
-        c["blocks"] = jax.tree.map(lambda x, v: x.at[:, slot].set(v),
-                                   self.cache["blocks"], state["blocks"])
-        c["tail"] = jax.tree.map(lambda x, v: x.at[slot].set(v),
-                                 self.cache["tail"], state["tail"])
-        if "shared" in self.cache:
-            c["shared"] = jax.tree.map(lambda x, v: x.at[:, slot].set(v),
-                                       self.cache["shared"], state["shared"])
-        if "tail_shared" in self.cache:
-            c["tail_shared"] = jax.tree.map(lambda x, v: x.at[slot].set(v),
-                                            self.cache["tail_shared"],
-                                            state["tail_shared"])
-        self.cache = c
+        """Install an `export_slot` payload into ``slot`` (overwrites it).
+        The payload may come from an engine on another device; it is
+        copied onto this engine's device first."""
+        arrays = {k: v for k, v in state.items() if k != "offset"}
+        self.cache = _write_slot(self.cache, slot,
+                                 jax.device_put(arrays, self.device))
         self.offsets[slot] = state["offset"]

@@ -172,3 +172,111 @@ class TestServeEngine:
         solo = run([[5, 6, 7]])[0]
         paired = run([[5, 6, 7], [9, 10, 11, 12]])[0]
         assert solo == paired
+
+    def test_flash_decode_engine_matches_ref(self):
+        """The Pallas decode-attention path through the whole engine: the
+        same requests give the same logits at every step as `ref`."""
+        cfg = reduced(get_config("qwen1.5-0.5b"), vocab_size=64)
+        params = init_lm(KEY, cfg)
+
+        def run(attn_impl):
+            eng = ServeEngine(dataclasses.replace(cfg, attn_impl=attn_impl),
+                              params, batch_slots=2, max_len=64, eos_id=-1)
+            for i, p in enumerate([[5, 6, 7], [9, 10, 11, 12, 13]]):
+                eng.submit(Request(i, prompt=p, max_new_tokens=6))
+            logits = []
+            while eng.queue or any(eng.slots):
+                logits.append(np.asarray(eng.step()))
+            return np.stack(logits), {r.req_id: r.output for r in eng.finished}
+
+        ref_logits, ref_out = run("ref")
+        flash_logits, flash_out = run("flash_decode")
+        np.testing.assert_allclose(flash_logits, ref_logits, rtol=1e-5, atol=1e-5)
+        assert flash_out == ref_out
+
+    def test_params_and_cache_live_on_the_engine_device(self):
+        cfg = reduced(get_config("qwen1.5-0.5b"), vocab_size=64)
+        dev = jax.devices()[-1]
+        eng = ServeEngine(cfg, init_lm(KEY, cfg), batch_slots=2, max_len=32,
+                          eos_id=-1, device=dev)
+        eng.submit(Request(0, prompt=[1, 2], max_new_tokens=3))
+        eng.run_until_done(100)
+        for leaf in jax.tree.leaves((eng.params, eng.cache)):
+            assert leaf.devices() == {dev}
+
+
+def _eager_reset_slot(cache, slot):
+    """The eager per-leaf update `reset_slot` was before it was jitted."""
+    out = dict(cache)
+    out["index"] = cache["index"].at[slot].set(0)
+    out["blocks"] = jax.tree.map(lambda x: x.at[:, slot].set(0), cache["blocks"])
+    out["tail"] = jax.tree.map(lambda x: x.at[slot].set(0), cache["tail"])
+    if "shared" in cache:
+        out["shared"] = jax.tree.map(lambda x: x.at[:, slot].set(0), cache["shared"])
+    if "tail_shared" in cache:
+        out["tail_shared"] = jax.tree.map(lambda x: x.at[slot].set(0),
+                                          cache["tail_shared"])
+    return out
+
+
+def _eager_import_slot(cache, slot, state):
+    """The eager per-leaf update `import_slot` was before it was jitted."""
+    c = dict(cache)
+    c["index"] = cache["index"].at[slot].set(state["index"])
+    c["blocks"] = jax.tree.map(lambda x, v: x.at[:, slot].set(v),
+                               cache["blocks"], state["blocks"])
+    c["tail"] = jax.tree.map(lambda x, v: x.at[slot].set(v),
+                             cache["tail"], state["tail"])
+    if "shared" in cache:
+        c["shared"] = jax.tree.map(lambda x, v: x.at[:, slot].set(v),
+                                   cache["shared"], state["shared"])
+    if "tail_shared" in cache:
+        c["tail_shared"] = jax.tree.map(lambda x, v: x.at[slot].set(v),
+                                        cache["tail_shared"], state["tail_shared"])
+    return c
+
+
+class TestSlotUpdates:
+    """The jitted, cache-donating slot updates the engine uses give the
+    same caches as the eager per-leaf updates they replaced."""
+
+    @staticmethod
+    def _random_engine(arch, seed):
+        cfg = reduced(get_config(arch), vocab_size=64)
+        eng = ServeEngine(cfg, init_lm(KEY, cfg), batch_slots=3, max_len=16)
+        keys = iter(jax.random.split(jax.random.PRNGKey(seed), 64))
+        eng.cache = jax.tree.map(
+            lambda x: (jax.random.randint(next(keys), x.shape, 0, 16, x.dtype)
+                       if x.dtype == jnp.int32
+                       else jax.random.normal(next(keys), x.shape, x.dtype)),
+            eng.cache)
+        return eng
+
+    @staticmethod
+    def _assert_same(a, b):
+        assert jax.tree.structure(a) == jax.tree.structure(b)
+        for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+    @pytest.mark.parametrize("arch", ["qwen1.5-0.5b", "zamba2-7b", "xlstm-1.3b"])
+    def test_reset_slot_matches_eager(self, arch):
+        eng = self._random_engine(arch, 1)
+        want = _eager_reset_slot(eng.cache, 1)
+        eng._admit(1, Request(0, prompt=[1]))
+        self._assert_same(eng.cache, want)
+
+    @pytest.mark.parametrize("arch", ["qwen1.5-0.5b", "zamba2-7b", "xlstm-1.3b"])
+    def test_export_import_slot_match_eager(self, arch):
+        src, dst = self._random_engine(arch, 2), self._random_engine(arch, 3)
+        src.offsets[0] = 7
+        state = src.export_slot(0)
+        assert state["offset"] == 7
+        want_state = {"index": src.cache["index"][0],
+                      "blocks": jax.tree.map(lambda x: x[:, 0], src.cache["blocks"]),
+                      "tail": jax.tree.map(lambda x: x[0], src.cache["tail"])}
+        for key in ("blocks", "tail", "index"):
+            self._assert_same(state[key], want_state[key])
+        want = _eager_import_slot(dst.cache, 2, state)
+        dst.import_slot(2, state)
+        self._assert_same(dst.cache, want)
+        assert dst.offsets[2] == 7
