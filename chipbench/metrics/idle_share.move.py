@@ -1,0 +1,8 @@
+"""1 - (union of device-operation intervals / traced window), averaged
+over the cell's chips."""
+
+from chipbench.readings import idle_share
+
+
+def read(run):
+    return idle_share(run)
