@@ -23,6 +23,12 @@ from .layers import (apply_linear, apply_mrope, apply_rope, apply_rope_tables,
 
 NEG_INF = -1e30
 
+#: ``jax.named_scope`` names of the decode path's cache and attention work,
+#: listed in ``repro.serve.trace.SCOPES``.  They reach the op-name metadata
+#: of the compiled program's instructions and change no fusion.
+KV_SCOPE = "serve_kv"
+ATTN_SCOPE = "serve_attn"
+
 
 def init_attention(key, cfg: ModelConfig, dtype, cross: bool = False) -> Dict:
     kq, kk, kv, ko = jax.random.split(key, 4)
@@ -307,16 +313,20 @@ def attention(
             upd = lambda c, x: jax.vmap(
                 lambda cb, xb, ib: jax.lax.dynamic_update_slice_in_dim(
                     cb, xb.astype(cb.dtype), ib, axis=0))(c, x, idx)
-        k_cache = upd(cache["k"], k)
-        v_cache = upd(cache["v"], v)
+        with jax.named_scope(KV_SCOPE):
+            k_cache = upd(cache["k"], k)
+            v_cache = upd(cache["v"], v)
         new_cache = {"k": k_cache, "v": v_cache}
         kv_len = cache_index + S
-        if impl == "flash_decode" and S == 1:
-            from repro.kernels import ops as kops
-            out = kops.decode_attention(q, k_cache, v_cache, kv_len)
-        elif S == 1:
-            # Single-step decode: prefix mask only.
-            out = gqa_reference(q, k_cache, v_cache, causal=False, kv_len=kv_len)
+        if S == 1:
+            with jax.named_scope(ATTN_SCOPE):
+                if impl == "flash_decode":
+                    from repro.kernels import ops as kops
+                    out = kops.decode_attention(q, k_cache, v_cache, kv_len)
+                else:
+                    # Single-step decode: prefix mask only.
+                    out = gqa_reference(q, k_cache, v_cache, causal=False,
+                                        kv_len=kv_len)
         else:
             # Prefill-into-cache: causal with absolute offset.
             out = _self_attention_math(q, k_cache, v_cache, causal=True,

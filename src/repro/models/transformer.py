@@ -23,7 +23,8 @@ import jax.numpy as jnp
 
 from repro.parallel.context import constrain
 
-from .attention import _self_attention_math, attention, init_attention
+from .attention import (KV_SCOPE, _self_attention_math, attention,
+                        init_attention)
 from .config import (
     BLOCK_ATTN,
     BLOCK_MAMBA2,
@@ -384,8 +385,9 @@ def forward(
             if cfg.bf16_cotangent:
                 x = bf16_cotangent_barrier(x)
             block_slice, layer = xs
-            layer_cache = (None if stacked is None else
-                           jax.tree.map(lambda c: c[layer], stacked))
+            with jax.named_scope(KV_SCOPE):
+                layer_cache = (None if stacked is None else
+                               jax.tree.map(lambda c: c[layer], stacked))
             if layout.shared_attn:
                 x, sc = _apply_shared(
                     params["shared_attn"], x, cfg, positions,
@@ -404,10 +406,11 @@ def forward(
             if stacked is not None:
                 if layout.shared_attn:
                     new_layer["shared"] = sc
-                stacked = jax.tree.map(
-                    lambda c, n: jax.lax.dynamic_update_index_in_dim(
-                        c, n.astype(c.dtype), layer, 0),
-                    stacked, new_layer)
+                with jax.named_scope(KV_SCOPE):
+                    stacked = jax.tree.map(
+                        lambda c, n: jax.lax.dynamic_update_index_in_dim(
+                            c, n.astype(c.dtype), layer, 0),
+                        stacked, new_layer)
             return (x, aux, stacked), None
 
         body = period_fn

@@ -8,6 +8,7 @@ dry-run lowers for the prefill_32k / decode_32k / long_500k cells; the
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -16,6 +17,7 @@ import numpy as np
 
 from repro.models import ModelConfig, forward, init_cache, logits_fn
 from repro.models.transformer import encode, read_slot, reset_slot, write_slot
+from repro.serve.trace import span
 
 # Per-slot cache updates run jitted with the cache donated, so admission and
 # kv-ship import rewrite one slot in place instead of copying every leaf.
@@ -74,6 +76,9 @@ class Request:
     max_new_tokens: int = 32
     done: bool = False
     output: List[int] = dataclasses.field(default_factory=list)
+    # ``time.perf_counter()`` seconds: given a slot, first token emitted.
+    t_admit: Optional[float] = dataclasses.field(default=None, compare=False)
+    t_first: Optional[float] = dataclasses.field(default=None, compare=False)
 
 
 class ServeEngine:
@@ -130,6 +135,7 @@ class ServeEngine:
         # masked by kv_len; SSM/xLSTM states must be zeroed explicitly).
         self.cache = _reset_slot(self.cache, slot)
         req.output = []
+        req.t_admit, req.t_first = time.perf_counter(), None
 
     def _slot_tokens(self) -> np.ndarray:
         toks = np.zeros((len(self.slots), 1), np.int32)
@@ -147,22 +153,41 @@ class ServeEngine:
         """Admit queued requests into free slots and decode one token for
         every occupied slot.  Returns the step's logits (slots, 1, vocab),
         or None when there was nothing to decode."""
-        for i, s in enumerate(self.slots):
-            if s is None and self.queue:
-                self._admit(i, self.queue.pop(0))
-        if all(s is None for s in self.slots):
-            return None
-        tokens = jax.device_put(self._slot_tokens(), self.device)
-        self.cache, logits = self._decode(self.params, self.cache, tokens)
-        self.steps += 1
+        dev = self.device.id
+        with span("serve.step", device=dev):
+            free = [i for i, s in enumerate(self.slots) if s is None]
+            if free and self.queue:
+                with span("serve.admit", device=dev):
+                    for i in free[:len(self.queue)]:
+                        self._admit(i, self.queue.pop(0))
+            if all(s is None for s in self.slots):
+                return None
+            with span("serve.feed", device=dev):
+                tokens = jax.device_put(self._slot_tokens(), self.device)
+            with span("serve.launch", device=dev):
+                self.cache, logits = self._decode(self.params, self.cache,
+                                                  tokens)
+            self.steps += 1
+            with span("serve.wait", device=dev):
+                next_tok = self._next_tokens(logits)
+            with span("serve.emit", device=dev):
+                self._emit(next_tok)
+            return logits
+
+    def _next_tokens(self, logits: jax.Array) -> np.ndarray:
+        """Each slot's sampled token, on the host: waits for the step."""
         if self.temperature <= 0.0:
-            next_tok = np.asarray(sample(logits[:, 0], None, 0.0))
-        else:
-            next_tok = np.zeros(len(self.slots), np.int64)
-            for i, req in enumerate(self.slots):
-                if req is not None:
-                    next_tok[i] = int(sample(logits[i, 0], self._request_key(req),
-                                             self.temperature))
+            return np.asarray(sample(logits[:, 0], None, 0.0))
+        next_tok = np.zeros(len(self.slots), np.int64)
+        for i, req in enumerate(self.slots):
+            if req is not None:
+                next_tok[i] = int(sample(logits[i, 0], self._request_key(req),
+                                         self.temperature))
+        return next_tok
+
+    def _emit(self, next_tok: np.ndarray) -> None:
+        """Advance every live slot; a generating one gets its token, and a
+        finished request frees its slot."""
         for i, req in enumerate(self.slots):
             if req is None:
                 continue
@@ -170,13 +195,14 @@ class ServeEngine:
             pos = int(self.offsets[i])
             if pos >= len(req.prompt):  # generating
                 req.output.append(int(next_tok[i]))
+                if req.t_first is None:
+                    req.t_first = time.perf_counter()
                 if (len(req.output) >= req.max_new_tokens
                         or int(next_tok[i]) == self.eos_id
                         or pos >= self.max_len - 1):
                     req.done = True
                     self.finished.append(req)
                     self.slots[i] = None
-        return logits
 
     def run_until_done(self, max_steps: int = 10_000) -> List[Request]:
         while (self.queue or any(self.slots)) and self.steps < max_steps:
