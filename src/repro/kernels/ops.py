@@ -13,9 +13,11 @@ from __future__ import annotations
 import functools
 
 import jax
+import jax.numpy as jnp
 
 from . import decode_attention as _decode
 from . import flash_attention as _flash
+from . import ref
 from . import rmsnorm as _rmsnorm
 from . import ssm_scan as _ssm
 
@@ -40,6 +42,20 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
 def decode_attention(q, k_cache, v_cache, kv_len, block_k: int = 512):
     return _decode.decode_attention(q, k_cache, v_cache, kv_len,
                                     block_k=block_k, interpret=_interpret())
+
+
+def stacked_decode_attention(q, k_stack, v_stack, layer, kv_len):
+    """The default decode attention over a stacked, lane-dense cache.
+    Lowered for the TPU it is the Pallas kernel, which reads the layer
+    where it lies; for any other platform the XLA-native oracle, so that
+    CPU runs and the dry-run lower plain XLA ops (tests/test_kernels.py
+    checks the kernel, interpreted, against that oracle)."""
+    return jax.lax.platform_dependent(
+        q, k_stack, v_stack, jnp.asarray(layer, jnp.int32),
+        jnp.asarray(kv_len, jnp.int32),
+        tpu=functools.partial(_decode.stacked_decode_attention,
+                              interpret=False),
+        default=ref.stacked_decode_attention_ref)
 
 
 def rms_norm(x, scale, eps: float = 1e-5, block_rows: int = 256):

@@ -43,8 +43,41 @@ def init_attention(key, cfg: ModelConfig, dtype, cross: bool = False) -> Dict:
 
 
 def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int, dtype) -> Dict:
-    shape = (batch, max_len, cfg.n_kv_heads, cfg.d_head)
+    """A self-attention KV cache, lane-dense: ``(batch, max_len, Hkv·Dh)``.
+
+    A ``(…, Hkv, Dh)`` cache with heads of 64 would fill half of the TPU's
+    128 lanes, so XLA keeps a stack of them position-minor and relays each
+    layer to head-minor and back on every decode step; rows of ``Hkv·Dh``
+    lanes need no padding and no relayout."""
+    shape = (batch, max_len, cfg.n_kv_heads * cfg.d_head)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+
+def write_kv_rows(cache: jnp.ndarray, rows: jnp.ndarray, index,
+                  layer=None) -> jnp.ndarray:
+    """``cache`` with ``rows`` (B, S, Hkv·Dh) written at each sequence's
+    write offset ``index``: a scalar (lockstep) or (B,) (continuous
+    batching).  ``cache`` is one layer's (B, T, Hkv·Dh), or the stack
+    (L, B, T, Hkv·Dh) written at ``layer``.  Only the new rows move, in one
+    update, in place when the cache is donated; an offset past the end is
+    clamped."""
+    rows = rows.astype(cache.dtype)
+    lead = () if layer is None else (jnp.asarray(layer, jnp.int32),)
+    idx = jnp.asarray(index, jnp.int32)
+    if idx.ndim == 0:
+        zero = jnp.zeros((), jnp.int32)
+        upd = rows if layer is None else rows[None]
+        return jax.lax.dynamic_update_slice(cache, upd, (*lead, zero, idx, zero))
+    B, S, _ = rows.shape
+    pos = idx[:, None] + jnp.arange(S, dtype=jnp.int32)
+    return cache.at[(*lead, jnp.arange(B)[:, None], pos)].set(
+        rows, mode="clip", unique_indices=S == 1, indices_are_sorted=S == 1)
+
+
+def kv_heads(cache: jnp.ndarray, layer, d_head: int) -> jnp.ndarray:
+    """One layer of a lane-dense cache as (B, T, Hkv, Dh)."""
+    c = cache if layer is None else cache[layer]
+    return c.reshape(*c.shape[:-1], -1, d_head)
 
 
 def _split_heads(x, n_heads, d_head):
@@ -273,17 +306,18 @@ def attention(
     causal: bool = True,
     kv_input: Optional[jnp.ndarray] = None,   # cross-attention memory (B,Sk,d)
     cache: Optional[Dict] = None,
-    cache_index: Optional[jnp.ndarray] = None,  # scalar int32 write offset
+    cache_index: Optional[jnp.ndarray] = None,  # write offset: scalar or (B,)
+    cache_layer: Optional[jnp.ndarray] = None,  # layer of a stacked cache
     impl: Optional[str] = None,
     rope_cache=None,
 ) -> Tuple[jnp.ndarray, Optional[Dict]]:
     """Self- or cross-attention with optional KV cache.
 
     Modes:
-      * train/prefill: ``cache=None`` (prefill callers build a cache from the
-        returned k/v via `prefill_cache`), full-sequence causal.
-      * decode: ``cache`` + ``cache_index`` given, S == 1: write new k/v at
-        ``cache_index`` and attend over the valid prefix.
+      * train: ``cache=None``, full-sequence causal.
+      * cached: ``cache`` (lane-dense, `init_kv_cache`) + ``cache_index``
+        given: write the S new k/v rows at ``cache_index`` and attend over
+        the valid prefix (S == 1 decode, or S > 1 prefill-into-cache).
       * cross: ``kv_input`` given (no cache, no causality).
     """
     impl = impl or cfg.attn_impl
@@ -303,34 +337,35 @@ def attention(
 
     new_cache = None
     if cache is not None:
-        # Decode: scatter this step's k/v at the write offset — a scalar in
-        # lockstep decode, or per-row (B,) under continuous batching.
-        idx = jnp.asarray(cache_index)
-        if idx.ndim == 0:
-            upd = lambda c, x: jax.lax.dynamic_update_slice_in_dim(
-                c, x.astype(c.dtype), idx, axis=1)
-        else:
-            upd = lambda c, x: jax.vmap(
-                lambda cb, xb, ib: jax.lax.dynamic_update_slice_in_dim(
-                    cb, xb.astype(cb.dtype), ib, axis=0))(c, x, idx)
+        # Write this step's k/v rows at the write offset, then attend over
+        # the valid prefix.  ``cache_layer`` given, the leaves are the whole
+        # stack of layers and are written and read at that layer in place.
         with jax.named_scope(KV_SCOPE):
-            k_cache = upd(cache["k"], k)
-            v_cache = upd(cache["v"], v)
+            k_cache = write_kv_rows(cache["k"], k.reshape(B, S, -1),
+                                    cache_index, cache_layer)
+            v_cache = write_kv_rows(cache["v"], v.reshape(B, S, -1),
+                                    cache_index, cache_layer)
         new_cache = {"k": k_cache, "v": v_cache}
         kv_len = cache_index + S
         if S == 1:
+            from repro.kernels import ops as kops
             with jax.named_scope(ATTN_SCOPE):
                 if impl == "flash_decode":
-                    from repro.kernels import ops as kops
-                    out = kops.decode_attention(q, k_cache, v_cache, kv_len)
+                    out = kops.decode_attention(
+                        q, kv_heads(k_cache, cache_layer, cfg.d_head),
+                        kv_heads(v_cache, cache_layer, cfg.d_head), kv_len)
+                elif cache_layer is None:
+                    out = kops.stacked_decode_attention(
+                        q, k_cache[None], v_cache[None], 0, kv_len)
                 else:
-                    # Single-step decode: prefix mask only.
-                    out = gqa_reference(q, k_cache, v_cache, causal=False,
-                                        kv_len=kv_len)
+                    out = kops.stacked_decode_attention(
+                        q, k_cache, v_cache, cache_layer, kv_len)
         else:
             # Prefill-into-cache: causal with absolute offset.
-            out = _self_attention_math(q, k_cache, v_cache, causal=True,
-                                       q_offset=cache_index, kv_len=kv_len)
+            out = _self_attention_math(
+                q, kv_heads(k_cache, cache_layer, cfg.d_head),
+                kv_heads(v_cache, cache_layer, cfg.d_head), causal=True,
+                q_offset=cache_index, kv_len=kv_len)
     else:
         if impl == "flash" and kv_input is None and causal:
             from repro.kernels import ops as kops
@@ -342,9 +377,3 @@ def attention(
     out = out.reshape(B, S, cfg.n_heads * cfg.d_head)
     return apply_linear(params["wo"], out, cd), new_cache
 
-
-def prefill_cache(cfg: ModelConfig, k: jnp.ndarray, v: jnp.ndarray, max_len: int) -> Dict:
-    """Extend prefill-computed k/v to a full-size cache (right-padded)."""
-    B, S, Hkv, Dh = k.shape
-    pad = [(0, 0), (0, max_len - S), (0, 0), (0, 0)]
-    return {"k": jnp.pad(k, pad), "v": jnp.pad(v, pad)}

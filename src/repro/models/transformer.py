@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from repro.parallel.context import constrain
 
 from .attention import (KV_SCOPE, _self_attention_math, attention,
-                        init_attention)
+                        init_attention, init_kv_cache)
 from .config import (
     BLOCK_ATTN,
     BLOCK_MAMBA2,
@@ -123,8 +123,7 @@ def init_block_cache(cfg: ModelConfig, kind: str, batch: int, max_len: int,
                      cross_len: int = 0) -> Dict:
     cd = dtype_of(cfg.compute_dtype)
     if kind in (BLOCK_ATTN, BLOCK_MOE):
-        shape = (batch, max_len, cfg.n_kv_heads, cfg.d_head)
-        c = {"attn": {"k": jnp.zeros(shape, cd), "v": jnp.zeros(shape, cd)}}
+        c = {"attn": init_kv_cache(cfg, batch, max_len, cd)}
         if cross_len:
             xs = (batch, cross_len, cfg.n_kv_heads, cfg.d_head)
             c["cross"] = {"k": jnp.zeros(xs, cd), "v": jnp.zeros(xs, cd)}
@@ -247,6 +246,29 @@ def reset_slot(cache: Dict, slot) -> Dict:
 
 
 # --------------------------------------------------------------- forward --
+def _whole_stack(path) -> bool:
+    """A scanned self-attention K/V leaf: it stays whole in the layer scan."""
+    keys = [getattr(p, "key", None) for p in path]
+    return len(keys) >= 2 and keys[-2] == "attn" and keys[-1] in ("k", "v")
+
+
+def _layer_view(stacked, layer):
+    """What a period's blocks get of the stacked caches: self-attention K/V
+    whole, every other leaf sliced at ``layer``."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, c: c if _whole_stack(path) else c[layer], stacked)
+
+
+def _layer_store(stacked, new_layer, layer):
+    """The stacked caches after a period: self-attention K/V as the blocks
+    returned them (rows already written), every other leaf's layer written
+    back at ``layer``."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, c, n: n if _whole_stack(path) else
+        jax.lax.dynamic_update_index_in_dim(c, n.astype(c.dtype), layer, 0),
+        stacked, new_layer)
+
+
 def _bar(x, cfg):
     return bf16_cotangent_barrier(x) if cfg.bf16_cotangent else x
 
@@ -261,14 +283,14 @@ def _psum_bar(x, cfg):
 
 
 def _attn_block(bp, x, cfg, positions, cache, index, encoder_out, kind,
-                rope_cache=None):
+                rope_cache=None, layer=None):
     aux = jnp.zeros((), jnp.float32)
     h = _bar(rms_norm(x, bp["norm1"]["scale"], cfg.norm_eps), cfg)
     a, attn_cache = attention(
         bp["attn"], h, cfg, positions, causal=True,
         cache=None if cache is None else cache["attn"],
         cache_index=None if cache is None else index,
-        rope_cache=rope_cache,
+        cache_layer=layer, rope_cache=rope_cache,
     )
     x = x + _psum_bar(a, cfg)
     new_cache = None if cache is None else dict(cache, attn=attn_cache)
@@ -302,10 +324,12 @@ def _attn_block(bp, x, cfg, positions, cache, index, encoder_out, kind,
 
 
 def apply_block(kind, bp, x, cfg, *, positions, cache, index, encoder_out=None,
-                rope_cache=None):
+                rope_cache=None, layer=None):
+    """``layer`` given, ``cache``'s self-attention K/V are the scan's whole
+    stacks, written and read at that layer (`_layer_view`)."""
     if kind in (BLOCK_ATTN, BLOCK_MOE):
         return _attn_block(bp, x, cfg, positions, cache, index, encoder_out, kind,
-                           rope_cache)
+                           rope_cache, layer)
     h = _bar(rms_norm(x, bp["norm1"]["scale"], cfg.norm_eps), cfg)
     mixer_cache = None if cache is None else cache["mixer"]
     if kind == BLOCK_MAMBA2:
@@ -320,14 +344,15 @@ def apply_block(kind, bp, x, cfg, *, positions, cache, index, encoder_out=None,
     return x + _psum_bar(m, cfg), new_cache, jnp.zeros((), jnp.float32)
 
 
-def _apply_shared(shared, x, cfg, positions, cache, index, rope_cache=None):
+def _apply_shared(shared, x, cfg, positions, cache, index, rope_cache=None,
+                  layer=None):
     """Zamba2's weight-shared attention block (own per-depth KV cache)."""
     h = _bar(rms_norm(x, shared["norm1"]["scale"], cfg.norm_eps), cfg)
     a, attn_cache = attention(
         shared["attn"], h, cfg, positions, causal=True,
         cache=None if cache is None else cache["attn"],
         cache_index=None if cache is None else index,
-        rope_cache=rope_cache,
+        cache_layer=layer, rope_cache=rope_cache,
     )
     x = x + a
     h2 = _bar(rms_norm(x, shared["norm2"]["scale"], cfg.norm_eps), cfg)
@@ -370,9 +395,12 @@ def forward(
 
     # ------------------------------------------------------ scanned periods
     if layout.n_full:
-        # The stacked per-layer caches ride in the scan carry and each period
-        # writes its layer back in place, so a donated cache is updated
-        # without a second whole-cache buffer (as scan outputs it would be).
+        # The stacked per-layer caches ride in the scan carry, so a donated
+        # cache is updated in place without a second whole-cache buffer (as
+        # scan outputs it would be).  Self-attention K/V stay whole: each
+        # layer writes its new rows into the stack and reads its layer where
+        # it lies.  Recurrent and cross-attention leaves, a layer's few KB
+        # to MB, are sliced out and their layer written back.
         stacked = None
         if cache is not None:
             stacked = {"blocks": cache["blocks"]}
@@ -387,12 +415,13 @@ def forward(
             block_slice, layer = xs
             with jax.named_scope(KV_SCOPE):
                 layer_cache = (None if stacked is None else
-                               jax.tree.map(lambda c: c[layer], stacked))
+                               _layer_view(stacked, layer))
+            at = None if stacked is None else layer
             if layout.shared_attn:
                 x, sc = _apply_shared(
                     params["shared_attn"], x, cfg, positions,
                     None if layer_cache is None else layer_cache["shared"],
-                    index, rope_cache)
+                    index, rope_cache, at)
             new_layer = {"blocks": {}}
             for j, kind in enumerate(layout.period_kinds):
                 cj = (None if layer_cache is None
@@ -400,17 +429,14 @@ def forward(
                 x, cj_new, a = apply_block(
                     kind, block_slice[f"pos{j}"], x, cfg,
                     positions=positions, cache=cj, index=index,
-                    encoder_out=encoder_out, rope_cache=rope_cache)
+                    encoder_out=encoder_out, rope_cache=rope_cache, layer=at)
                 new_layer["blocks"][f"pos{j}"] = cj_new
                 aux = aux + a
             if stacked is not None:
                 if layout.shared_attn:
                     new_layer["shared"] = sc
                 with jax.named_scope(KV_SCOPE):
-                    stacked = jax.tree.map(
-                        lambda c, n: jax.lax.dynamic_update_index_in_dim(
-                            c, n.astype(c.dtype), layer, 0),
-                        stacked, new_layer)
+                    stacked = _layer_store(stacked, new_layer, layer)
             return (x, aux, stacked), None
 
         body = period_fn

@@ -100,7 +100,8 @@ _PARAM_RULES = [
 # Down-proj of the mLSTM/sLSTM mixers overlaps "mixer/w_down" rule above.
 
 _CACHE_RULES = [
-    (r"(attn|cross)/(k|v)$",  (None, "dp", "seq", None, None)),   # B,S,Hkv,Dh (+layer)
+    (r"attn/(k|v)$",          (None, "dp", "seq", None)),         # B,S,Hkv·Dh (+layer)
+    (r"cross/(k|v)$",         (None, "dp", "seq", None, None)),   # B,S,Hkv,Dh (+layer)
     (r"mixer/conv$",          ("dp", None, "tp")),
     (r"mixer/state$",         ("dp", "tp", None, None)),          # B,H,P,N
     (r"mixer/C$",             ("dp", "tp", None, None)),

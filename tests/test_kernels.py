@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.kernels import decode_attention as _decode
 from repro.kernels import ops, ref
 
 KEY = jax.random.PRNGKey(0)
@@ -77,6 +78,58 @@ class TestDecodeAttention:
         v2 = v.at[:, 64:].set(-999.0)
         out2 = ops.decode_attention(q, k2, v2, kv_len, block_k=64)
         np.testing.assert_allclose(np.asarray(out1), np.asarray(out2))
+
+
+class TestStackedDecodeAttention:
+    """The decode kernel over a stacked, lane-dense (L, B, Sk, Hkv*D) cache,
+    interpreted, against the oracle on the layer it names."""
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("L,B,Sk,Hq,Hkv,D,block_bytes", [
+        (3, 4, 64, 8, 2, 64, 1 << 20),      # GQA 4:1, two heads per tile
+        (2, 3, 128, 4, 4, 64, 1 << 14),     # MHA, four key blocks
+        (2, 2, 64, 8, 1, 128, 1 << 20),     # MQA, one head per tile
+        (2, 2, 32, 6, 3, 32, 1 << 20),      # heads of 32, not lane-aligned
+        (1, 2, 64, 4, 1, 64, 1 << 20),      # one head narrower than a tile
+    ])
+    def test_matches_ref(self, L, B, Sk, Hq, Hkv, D, block_bytes, dtype):
+        q = _rand((B, 1, Hq, D), dtype, 20)
+        k = _rand((L, B, Sk, Hkv * D), dtype, 21)
+        v = _rand((L, B, Sk, Hkv * D), dtype, 22)
+        kv_len = jnp.arange(1, B + 1, dtype=jnp.int32) * (Sk // (B + 1)) + 1
+        for layer in range(L):
+            out = _decode.stacked_decode_attention(
+                q, k, v, jnp.int32(layer), kv_len, block_bytes=block_bytes,
+                interpret=True)
+            want = ref.stacked_decode_attention_ref(q, k, v, layer, kv_len)
+            np.testing.assert_allclose(np.asarray(out, np.float32),
+                                       np.asarray(want, np.float32),
+                                       **_tol(dtype))
+
+    def test_other_layers_and_stale_rows_are_not_read(self):
+        q = _rand((2, 1, 4, 64), jnp.float32, 23)
+        k = _rand((3, 2, 128, 128), jnp.float32, 24)
+        v = _rand((3, 2, 128, 128), jnp.float32, 25)
+        kv_len = jnp.array([40, 97], jnp.int32)
+
+        def run(k, v):
+            return np.asarray(_decode.stacked_decode_attention(
+                q, k, v, jnp.int32(1), kv_len, block_bytes=1 << 14,
+                interpret=True))
+        want = run(k, v)
+        for b, n in enumerate([40, 97]):
+            k = k.at[1, b, n:].set(999.0).at[0].set(-999.0).at[2].set(7.0)
+            v = v.at[1, b, n:].set(-999.0).at[0].set(999.0).at[2].set(7.0)
+        np.testing.assert_allclose(run(k, v), want)
+
+    def test_ops_off_the_chip_is_the_oracle(self):
+        q = _rand((2, 1, 8, 64), jnp.float32, 26)
+        k = _rand((2, 2, 64, 128), jnp.float32, 27)
+        v = _rand((2, 2, 64, 128), jnp.float32, 28)
+        kv_len = jnp.array([5, 64], jnp.int32)
+        np.testing.assert_array_equal(
+            np.asarray(ops.stacked_decode_attention(q, k, v, 1, kv_len)),
+            np.asarray(ref.stacked_decode_attention_ref(q, k, v, 1, kv_len)))
 
 
 class TestRmsNorm:

@@ -6,7 +6,10 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and under several test workers the others
 must still collect the same tests (they skip here instead)."""
 
+import dataclasses
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,8 +21,11 @@ from repro.kernels import decode_attention as _decode
 from repro.kernels import flash_attention as _flash
 from repro.kernels import rmsnorm as _rmsnorm
 from repro.kernels import ssm_scan as _ssm
+from repro.models import init_cache, init_lm
+from repro.serve.engine import make_decode_step
 
 QWEN = get_config("qwen1.5-0.5b")      # 16 heads (kv 16) of 64, d_model 1024
+GRANITE = get_config("granite-3-2b")   # 32 heads (kv 8) of 64, d_model 2048
 ZAMBA = get_config("zamba2-7b")        # Mamba2: d_inner 7168 = 112 heads of 64
 
 
@@ -60,6 +66,81 @@ def test_decode_attention_compiles(one_chip, B, Sk, Hq, Hkv, D):
         lambda q, k, v, n: _decode.decode_attention(q, k, v, n, interpret=False),
         [((B, 1, Hq, D), BF16), ((B, Sk, Hkv, D), BF16),
          ((B, Sk, Hkv, D), BF16), ((B,), I32)], one_chip)
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("L,B,Sk,Hq,Hkv,D", [
+    (40, 32, 2048, 32, 8, 64),      # granite: two heads per 128-lane tile
+    (24, 32, 2048, 16, 16, 64),     # qwen, MHA
+    (4, 8, 4096, 32, 4, 128),       # one head per tile, two key blocks
+])
+def test_stacked_decode_attention_compiles(one_chip, L, B, Sk, Hq, Hkv, D):
+    hlo = _compile(
+        lambda q, k, v, layer, n: _decode.stacked_decode_attention(
+            q, k, v, layer, n, interpret=False),
+        [((B, 1, Hq, D), BF16), ((L, B, Sk, Hkv * D), BF16),
+         ((L, B, Sk, Hkv * D), BF16), ((), I32), ((B,), I32)], one_chip)
+    assert "tpu_custom_call" in hlo
+
+
+_HLO_DEF = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\S+?)(?:\{[^}]*\})? (\S+?)\((.*)$")
+
+
+def _elements(shape: str) -> int:
+    m = re.match(r"^\w+\[([\d,]*)\]", shape)
+    return math.prod(int(d) for d in m.group(1).split(",") if d) if m else 0
+
+
+def _cache_sized_moves(hlo: str, layer: int):
+    """Instructions of a compiled program that move ``layer`` elements or
+    more: a ``copy`` or ``dynamic-slice`` result that large (fusions count
+    by the name XLA gives them after their root), or a
+    ``dynamic-update-slice`` that writes that much.  A dynamic-update-slice
+    whose update is small is in place on its buffer and passes."""
+    size, defs = {}, []
+    for line in hlo.splitlines():
+        m = _HLO_DEF.match(line)
+        if m:
+            name, shape, op, rest = m.groups()
+            size[name] = _elements(shape)
+            defs.append((name, op if op != "fusion" else name, rest))
+    bad = []
+    for name, kind, rest in defs:
+        operands = re.findall(r"%([\w.\-]+)", rest.split(")")[0])
+        if "dynamic-update-slice" in kind:
+            if any(size.get(o, 0) >= layer for o in operands[1:]):
+                bad.append(name)
+        elif kind.startswith("copy") and kind not in ("copy-start", "copy-done"):
+            if size[name] >= layer:
+                bad.append(name)
+        elif "dynamic-slice" in kind and size[name] >= layer:
+            bad.append(name)
+    return bad
+
+
+def test_decode_step_keeps_the_stacked_cache_in_place(one_chip):
+    """The continuous-batching decode step on a cut of granite-3-2b (2
+    layers at published widths, 32 slots x 2048): each layer writes only
+    its new rows into the donated stacked cache and reads its layer where
+    it lies.  No instruction copies, slices or rewrites a layer's K or V
+    (B x max_len x Hkv*Dh), and the temporaries stay far below one layer
+    (64 MiB).  A cache stored (..., Hkv, 64) is kept position-minor and
+    relaid twice a layer: 404 MB of temporaries."""
+    cfg = dataclasses.replace(GRANITE, n_layers=2)
+    B, T = 32, 2048
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+    params = shaped(jax.eval_shape(lambda: init_lm(jax.random.PRNGKey(0), cfg)))
+    cache = shaped(jax.eval_shape(
+        lambda: init_cache(cfg, B, T, per_slot_index=True)))
+    tokens = jax.ShapeDtypeStruct((B, 1), I32, sharding=one_chip)
+    compiled = jax.jit(make_decode_step(cfg), donate_argnums=(1,)).lower(
+        params, cache, tokens).compile()
+    hlo = compiled.as_text()
+    assert _cache_sized_moves(hlo, B * T * cfg.n_kv_heads * cfg.d_head) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
     assert "tpu_custom_call" in hlo
 
 
