@@ -8,6 +8,7 @@ dry-run lowers for the prefill_32k / decode_32k / long_500k cells; the
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -17,13 +18,23 @@ import numpy as np
 
 from repro.models import ModelConfig, forward, init_cache, logits_fn
 from repro.models.transformer import encode, read_slot, reset_slot, write_slot
-from repro.serve.trace import span
+from repro.serve.trace import MOVE_SCOPE, span
+
+
+def _move_scoped(fn):
+    """``fn`` traced under the move's device scope."""
+    @functools.wraps(fn)
+    def scoped(*args):
+        with jax.named_scope(MOVE_SCOPE):
+            return fn(*args)
+    return scoped
+
 
 # Per-slot cache updates run jitted with the cache donated, so admission and
 # kv-ship import rewrite one slot in place instead of copying every leaf.
-_read_slot = jax.jit(read_slot)
+_read_slot = jax.jit(_move_scoped(read_slot))
 _reset_slot = jax.jit(reset_slot, donate_argnums=(0,))
-_write_slot = jax.jit(write_slot, donate_argnums=(0,))
+_write_slot = jax.jit(_move_scoped(write_slot), donate_argnums=(0,))
 
 
 def make_prefill_step(cfg: ModelConfig, max_len: int, cross_len: int = 0):
@@ -110,6 +121,9 @@ class ServeEngine:
         # Per-slot write offsets (slot-local KV positions).
         self.offsets = np.zeros(batch_slots, np.int32)
         self._decode = jax.jit(make_decode_step(cfg), donate_argnums=(1,))
+        self._slot_bytes = sum(
+            int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(
+                jax.eval_shape(read_slot, self.cache, 0)))
         self._base_key = jax.random.PRNGKey(rng_seed)
         self.steps = 0
 
@@ -218,8 +232,11 @@ class ServeEngine:
     # bit-identically.
     def export_slot(self, slot: int) -> Dict:
         """Copy out one slot's KV/recurrent state + write offset."""
-        state = _read_slot(self.cache, slot)
-        state["offset"] = int(self.offsets[slot])
+        offset = int(self.offsets[slot])
+        with span("serve.export", device=self.device.id,
+                  bytes=self._slot_bytes, positions=offset):
+            state = _read_slot(self.cache, slot)
+        state["offset"] = offset
         return state
 
     def import_slot(self, slot: int, state: Dict) -> None:
@@ -227,6 +244,9 @@ class ServeEngine:
         The payload may come from an engine on another device; it is
         copied onto this engine's device first."""
         arrays = {k: v for k, v in state.items() if k != "offset"}
-        self.cache = _write_slot(self.cache, slot,
-                                 jax.device_put(arrays, self.device))
+        with span("serve.import", device=self.device.id,
+                  bytes=sum(x.nbytes for x in jax.tree.leaves(arrays)),
+                  positions=int(state["offset"])):
+            self.cache = _write_slot(self.cache, slot,
+                                     jax.device_put(arrays, self.device))
         self.offsets[slot] = state["offset"]

@@ -6,8 +6,9 @@ costs one check.  The
 engine gives every span ``device=<chip id>``, so that a reduction can key
 it to the chip whose work it drives.  ``SCOPES`` are the
 ``jax.named_scope`` names of the decode program's cache and attention
-work: they reach the op-name metadata of its compiled instructions, whose
-names the device trace's operations carry.
+work, and ``MOVE_SCOPE`` that of the programs that read and write one
+slot for a move: they reach the op-name metadata of the compiled
+instructions, whose names the device trace's operations carry.
 """
 
 from __future__ import annotations
@@ -18,12 +19,19 @@ from repro.models.attention import ATTN_SCOPE, KV_SCOPE
 
 #: Host spans of ``ServeEngine.step``: the whole step, then its phases.
 #: ``serve.admit`` opens only on steps that give a request a slot.
+#: ``serve.export`` and ``serve.import`` are a session's move
+#: (``export_slot``, ``import_slot``); they also carry ``bytes`` (the
+#: payload's size) and ``positions`` (the slot's write offset).
 SPANS = ("serve.step", "serve.admit", "serve.feed", "serve.launch",
-         "serve.wait", "serve.emit")
+         "serve.wait", "serve.emit", "serve.export", "serve.import")
 
 #: Device scopes: everything the KV cache costs inside the layer scan, and
 #: the decode attention math.
 SCOPES = (KV_SCOPE, ATTN_SCOPE)
+
+#: Device scope of the slot read and write programs a move runs; apart
+#: from ``SCOPES``, which name the decode program's work only.
+MOVE_SCOPE = "serve_move"
 
 
 def span(name: str, **args) -> jax.profiler.TraceAnnotation:
