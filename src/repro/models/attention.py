@@ -124,6 +124,8 @@ def gqa_reference(
         scores = jnp.where(mask[:, None, None], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgst,btkd->bskgd", probs, v.astype(jnp.float32))
+    if kv_len is not None:  # a row with no valid entry reads zeros
+        out = jnp.where(kvl[:, None, None, None, None] > 0, out, 0.0)
     return out.reshape(B, Sq, Hq, Dh).astype(q.dtype)
 
 
@@ -310,6 +312,7 @@ def attention(
     cache_layer: Optional[jnp.ndarray] = None,  # layer of a stacked cache
     impl: Optional[str] = None,
     rope_cache=None,
+    kv_len: Optional[jnp.ndarray] = None,  # positions read; cache_index + S
 ) -> Tuple[jnp.ndarray, Optional[Dict]]:
     """Self- or cross-attention with optional KV cache.
 
@@ -346,7 +349,8 @@ def attention(
             v_cache = write_kv_rows(cache["v"], v.reshape(B, S, -1),
                                     cache_index, cache_layer)
         new_cache = {"k": k_cache, "v": v_cache}
-        kv_len = cache_index + S
+        if kv_len is None:
+            kv_len = cache_index + S
         if S == 1:
             from repro.kernels import ops as kops
             with jax.named_scope(ATTN_SCOPE):

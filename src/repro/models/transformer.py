@@ -283,14 +283,14 @@ def _psum_bar(x, cfg):
 
 
 def _attn_block(bp, x, cfg, positions, cache, index, encoder_out, kind,
-                rope_cache=None, layer=None):
+                rope_cache=None, layer=None, kv_len=None):
     aux = jnp.zeros((), jnp.float32)
     h = _bar(rms_norm(x, bp["norm1"]["scale"], cfg.norm_eps), cfg)
     a, attn_cache = attention(
         bp["attn"], h, cfg, positions, causal=True,
         cache=None if cache is None else cache["attn"],
         cache_index=None if cache is None else index,
-        cache_layer=layer, rope_cache=rope_cache,
+        cache_layer=layer, rope_cache=rope_cache, kv_len=kv_len,
     )
     x = x + _psum_bar(a, cfg)
     new_cache = None if cache is None else dict(cache, attn=attn_cache)
@@ -324,12 +324,13 @@ def _attn_block(bp, x, cfg, positions, cache, index, encoder_out, kind,
 
 
 def apply_block(kind, bp, x, cfg, *, positions, cache, index, encoder_out=None,
-                rope_cache=None, layer=None):
+                rope_cache=None, layer=None, kv_len=None):
     """``layer`` given, ``cache``'s self-attention K/V are the scan's whole
-    stacks, written and read at that layer (`_layer_view`)."""
+    stacks, written and read at that layer (`_layer_view`).  ``kv_len``,
+    the positions attention reads, defaults to ``index`` + the new rows."""
     if kind in (BLOCK_ATTN, BLOCK_MOE):
         return _attn_block(bp, x, cfg, positions, cache, index, encoder_out, kind,
-                           rope_cache, layer)
+                           rope_cache, layer, kv_len)
     h = _bar(rms_norm(x, bp["norm1"]["scale"], cfg.norm_eps), cfg)
     mixer_cache = None if cache is None else cache["mixer"]
     if kind == BLOCK_MAMBA2:
@@ -345,14 +346,14 @@ def apply_block(kind, bp, x, cfg, *, positions, cache, index, encoder_out=None,
 
 
 def _apply_shared(shared, x, cfg, positions, cache, index, rope_cache=None,
-                  layer=None):
+                  layer=None, kv_len=None):
     """Zamba2's weight-shared attention block (own per-depth KV cache)."""
     h = _bar(rms_norm(x, shared["norm1"]["scale"], cfg.norm_eps), cfg)
     a, attn_cache = attention(
         shared["attn"], h, cfg, positions, causal=True,
         cache=None if cache is None else cache["attn"],
         cache_index=None if cache is None else index,
-        cache_layer=layer, rope_cache=rope_cache,
+        cache_layer=layer, rope_cache=rope_cache, kv_len=kv_len,
     )
     x = x + a
     h2 = _bar(rms_norm(x, shared["norm2"]["scale"], cfg.norm_eps), cfg)
@@ -371,9 +372,12 @@ def forward(
     vision_embeds: Optional[jnp.ndarray] = None,  # (B, P, d) prefix stub
     input_embeds: Optional[jnp.ndarray] = None,   # bypass embedding (encoder stubs)
     decoding: bool = False,
+    live: Optional[jnp.ndarray] = None,  # (B,) bool, with a per-slot cache
 ) -> Tuple[jnp.ndarray, Optional[Dict], jnp.ndarray]:
     """Returns (hidden (B,S,d) — NOT logits; see `logits`/`lm_loss` —,
-    new_cache, aux_loss)."""
+    new_cache, aux_loss).  ``live`` given, a slot where it is False holds
+    no sequence: attention reads none of its cache and its write offset
+    stays."""
     cd = dtype_of(cfg.compute_dtype)
     layout = stack_layout(cfg)
     if input_embeds is not None:
@@ -388,6 +392,7 @@ def forward(
         offset = cache["index"] if cache is not None else 0
         positions = positions_for(cfg, B, S, offset)
     index = cache["index"] if cache is not None else None
+    kv_len = None if live is None else jnp.where(live, index + S, 0)
     rope_cache = rope_tables(cfg, positions) if cfg.hoist_rope else None
 
     aux_total = jnp.zeros((), jnp.float32)
@@ -421,7 +426,7 @@ def forward(
                 x, sc = _apply_shared(
                     params["shared_attn"], x, cfg, positions,
                     None if layer_cache is None else layer_cache["shared"],
-                    index, rope_cache, at)
+                    index, rope_cache, at, kv_len)
             new_layer = {"blocks": {}}
             for j, kind in enumerate(layout.period_kinds):
                 cj = (None if layer_cache is None
@@ -429,7 +434,8 @@ def forward(
                 x, cj_new, a = apply_block(
                     kind, block_slice[f"pos{j}"], x, cfg,
                     positions=positions, cache=cj, index=index,
-                    encoder_out=encoder_out, rope_cache=rope_cache, layer=at)
+                    encoder_out=encoder_out, rope_cache=rope_cache, layer=at,
+                    kv_len=kv_len)
                 new_layer["blocks"][f"pos{j}"] = cj_new
                 aux = aux + a
             if stacked is not None:
@@ -461,14 +467,16 @@ def forward(
         layer_idx = layout.n_full * layout.period + t
         if layout.shared_attn and layer_idx % cfg.shared_attn_every == 0:
             sc = cache["tail_shared"][shared_i] if cache is not None else None
-            x, sc_new = _apply_shared(params["shared_attn"], x, cfg, positions, sc, index)
+            x, sc_new = _apply_shared(params["shared_attn"], x, cfg, positions,
+                                      sc, index, kv_len=kv_len)
             if cache is not None:
                 new_cache.setdefault("tail_shared", []).append(sc_new)
             shared_i += 1
         cj = cache["tail"][t] if cache is not None else None
         x, cj_new, a = apply_block(kind, params["tail"][t], x, cfg,
                                    positions=positions, cache=cj, index=index,
-                                   encoder_out=encoder_out, rope_cache=rope_cache)
+                                   encoder_out=encoder_out, rope_cache=rope_cache,
+                                   kv_len=kv_len)
         x = constrain(x, ("dp", None, None))
         aux_total = aux_total + a
         if cache is not None:
@@ -478,7 +486,8 @@ def forward(
         x = bf16_cotangent_barrier(x)
     x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
     if cache is not None:
-        new_cache["index"] = cache["index"] + S
+        new_cache["index"] = (index + S if live is None
+                              else jnp.where(live, index + S, index))
     return x, new_cache, aux_total
 
 

@@ -16,7 +16,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.decode_attention import kv_block
 from repro.models import ModelConfig, forward, init_cache, logits_fn
+from repro.models.layers import dtype_of
 from repro.models.transformer import encode, read_slot, reset_slot, write_slot
 from repro.serve.trace import MOVE_SCOPE, span
 
@@ -63,11 +65,21 @@ def make_prefill_step(cfg: ModelConfig, max_len: int, cross_len: int = 0):
     return prefill
 
 
+#: The token a slot that holds no request is fed.
+NO_TOKEN = -1
+
+
 def make_decode_step(cfg: ModelConfig):
-    """(params, cache, tokens (B,1)) -> (cache, logits (B,1,V))."""
+    """(params, cache, tokens (B,1)) -> (cache, logits (B,1,V)).
+
+    With a per-slot cache, a slot fed ``NO_TOKEN`` holds no request:
+    attention reads none of its positions and its write offset stays;
+    its logits mean nothing."""
 
     def decode(params, cache, tokens):
-        hidden, cache, _ = forward(params, tokens, cfg, cache=cache)
+        live = tokens[:, 0] >= 0 if cache["index"].ndim else None
+        hidden, cache, _ = forward(params, jnp.maximum(tokens, 0), cfg,
+                                   cache=cache, live=live)
         return cache, logits_fn(params, hidden, cfg)
 
     return decode
@@ -124,6 +136,9 @@ class ServeEngine:
         self._slot_bytes = sum(
             int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(
                 jax.eval_shape(read_slot, self.cache, 0)))
+        # Positions decode attention copies at a time (`kv_block`).
+        self._kv_block = kv_block(max_len, cfg.n_kv_heads * cfg.d_head
+                                  * np.dtype(dtype_of(cfg.compute_dtype)).itemsize)
         self._base_key = jax.random.PRNGKey(rng_seed)
         self.steps = 0
 
@@ -152,7 +167,7 @@ class ServeEngine:
         req.t_admit, req.t_first = time.perf_counter(), None
 
     def _slot_tokens(self) -> np.ndarray:
-        toks = np.zeros((len(self.slots), 1), np.int32)
+        toks = np.full((len(self.slots), 1), NO_TOKEN, np.int32)
         for i, req in enumerate(self.slots):
             if req is None:
                 continue
@@ -177,8 +192,12 @@ class ServeEngine:
             if all(s is None for s in self.slots):
                 return None
             with span("serve.feed", device=dev):
-                tokens = jax.device_put(self._slot_tokens(), self.device)
-            with span("serve.launch", device=dev):
+                host_tokens = self._slot_tokens()
+                tokens = jax.device_put(host_tokens, self.device)
+            live = host_tokens[:, 0] != NO_TOKEN
+            blocks = -(-(self.offsets[live] + 1) // self._kv_block)
+            with span("serve.launch", device=dev, live=int(live.sum()),
+                      kv_positions=int(blocks.sum()) * self._kv_block):
                 self.cache, logits = self._decode(self.params, self.cache,
                                                   tokens)
             self.steps += 1

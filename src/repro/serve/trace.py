@@ -19,6 +19,9 @@ from repro.models.attention import ATTN_SCOPE, KV_SCOPE
 
 #: Host spans of ``ServeEngine.step``: the whole step, then its phases.
 #: ``serve.admit`` opens only on steps that give a request a slot.
+#: ``serve.launch`` also carries ``live`` (the slots fed a token) and
+#: ``kv_positions`` (the positions decode attention reads: each live
+#: slot's length, rounded up to the kernel's copy block).
 #: ``serve.export`` and ``serve.import`` are a session's move
 #: (``export_slot``, ``import_slot``); they also carry ``bytes`` (the
 #: payload's size) and ``positions`` (the slot's write offset).

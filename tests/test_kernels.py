@@ -106,21 +106,52 @@ class TestStackedDecodeAttention:
                                        np.asarray(want, np.float32),
                                        **_tol(dtype))
 
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("Hq,Hkv", [(32, 8), (16, 16)],
+                             ids=["gqa-512-lanes", "mha-1024-lanes"])
+    def test_lengths_around_a_block_match_ref(self, Hq, Hkv, dtype):
+        """Lengths 0, 1, block - 1, block, block + 1 and the whole cache in
+        one batch: the kernel copies each slot's live blocks only, and a
+        slot of length 0 reads zeros, as the oracle does."""
+        D, Sk, block = 64, 64, 16
+        W = Hkv * D
+        q = _rand((6, 1, Hq, D), dtype, 29)
+        k = _rand((2, 6, Sk, W), dtype, 30)
+        v = _rand((2, 6, Sk, W), dtype, 31)
+        kv_len = jnp.array([0, 1, block - 1, block, block + 1, Sk], jnp.int32)
+        row = W * jnp.dtype(dtype).itemsize
+        assert _decode.kv_block(Sk, row, block * row) == block
+        out = _decode.stacked_decode_attention(
+            q, k, v, jnp.int32(1), kv_len, block_bytes=block * row,
+            interpret=True)
+        want = ref.stacked_decode_attention_ref(q, k, v, 1, kv_len)
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(want, np.float32), **_tol(dtype))
+        np.testing.assert_array_equal(np.asarray(out[0], np.float32), 0.0)
+        np.testing.assert_array_equal(np.asarray(want[0], np.float32), 0.0)
+
     def test_other_layers_and_stale_rows_are_not_read(self):
-        q = _rand((2, 1, 4, 64), jnp.float32, 23)
-        k = _rand((3, 2, 128, 128), jnp.float32, 24)
-        v = _rand((3, 2, 128, 128), jnp.float32, 25)
-        kv_len = jnp.array([40, 97], jnp.int32)
+        """Rows past each length, whole free slots and the other layers
+        hold NaN: the output stays finite and the same."""
+        q = _rand((3, 1, 4, 64), jnp.float32, 23)
+        k = _rand((3, 3, 128, 128), jnp.float32, 24)
+        v = _rand((3, 3, 128, 128), jnp.float32, 25)
+        lens = [40, 0, 97]
+        kv_len = jnp.array(lens, jnp.int32)
 
         def run(k, v):
             return np.asarray(_decode.stacked_decode_attention(
                 q, k, v, jnp.int32(1), kv_len, block_bytes=1 << 14,
                 interpret=True))
         want = run(k, v)
-        for b, n in enumerate([40, 97]):
-            k = k.at[1, b, n:].set(999.0).at[0].set(-999.0).at[2].set(7.0)
-            v = v.at[1, b, n:].set(-999.0).at[0].set(999.0).at[2].set(7.0)
-        np.testing.assert_allclose(run(k, v), want)
+        k, v = k.at[0].set(np.nan).at[2].set(np.nan), v.at[0].set(-999.0)
+        v = v.at[2].set(np.nan)
+        for b, n in enumerate(lens):
+            k, v = k.at[1, b, n:].set(np.nan), v.at[1, b, n:].set(np.nan)
+        got = run(k, v)
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got, want)
+        np.testing.assert_array_equal(got[1], 0.0)
 
     def test_ops_off_the_chip_is_the_oracle(self):
         q = _rand((2, 1, 8, 64), jnp.float32, 26)
