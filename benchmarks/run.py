@@ -3,7 +3,6 @@
   bench_paper    — fig. 5(a)/(b) + solver-time claims (§4.2)
   bench_fleet    — fleet-runtime scenario × policy × scale sweep (repro.fleet)
   bench_roofline — §Roofline table from the dry-run artifacts
-  bench_kernels  — Pallas kernels (interpret) vs jnp refs
 
 Default mode prints ``name,key=value,...`` CSV rows for every section.
 ``--json`` runs the fleet sweep (scale ×1 scenario × policy grid, the
@@ -489,13 +488,12 @@ def run_report(seed: int) -> int:
 
 
 def run_csv(seed: int = 0) -> int:
-    from benchmarks import bench_fleet, bench_kernels, bench_paper, bench_roofline
+    from benchmarks import bench_fleet, bench_paper, bench_roofline
 
     sections = [
         ("paper", bench_paper.run),
         ("fleet", lambda: bench_fleet.run(seed=seed)),
         ("roofline", bench_roofline.run),
-        ("kernels", bench_kernels.run),
     ]
     failed = 0
     for name, fn in sections:

@@ -12,8 +12,6 @@ Phases (one chip, the default):
               forward of the same tokens at highest matmul precision
   kv-ship     export_slot mid-decode, import_slot into a second engine;
               its continuation equals the unmoved one token for token
-  kernel      the same decode step with attn_impl="flash_decode": compiled
-              natively (tpu_custom_call in its HLO) and equal to "ref"
   train-move  Trainer steps, then LiveElasticBackend save -> reshard ->
               resume through execute_move, and one more step; the restored
               state equals the saved one leaf for leaf
@@ -60,7 +58,6 @@ from repro.models import ModelConfig, forward, init_lm, logits_fn  # noqa: E402
 from repro.parallel.sharding import default_strategy  # noqa: E402
 from repro.runtime.elastic import MeshPlan  # noqa: E402
 from repro.serve import Request, ServeEngine  # noqa: E402
-from repro.serve.engine import make_decode_step  # noqa: E402
 from repro.train import make_optimizer  # noqa: E402
 from repro.train.trainer import TrainerConfig, make_synthetic_trainer  # noqa: E402
 
@@ -77,10 +74,6 @@ CKPT_DIR = ROOT / ".smoke_ckpt"
 # must fail it, or the tolerance is too loose to mean anything.
 LOGITS_TOL = 0.05
 CONTROL_MANTISSA_BITS = 3
-# flash_decode against ref on one decode step: both round the attention
-# output to bf16 and differ only in f32 accumulation order, so their gap
-# must stay inside the engine's own bf16 error budget.
-KERNEL_TOL = LOGITS_TOL
 # Loss on four chips against one chip, relative: the sharded step sums the
 # same f32 products in another order, and bf16 parameter updates carry
 # that difference forward one rounding (2^-8) at a time.
@@ -92,11 +85,10 @@ class SmokeSize:
     """How much work each phase does (the defaults are the chip run)."""
 
     slots: int = 16
-    max_len: int = 2048          # a multiple of flash_decode's 512 block
+    max_len: int = 2048
     n_requests: int = 32
     prompt_len: Sequence[int] = (32, 128)
     new_tokens: int = 32
-    kernel_steps: int = 600      # decode steps before the kernel comparison
     train_batch: int = 8
     train_seq: int = 512
     train_steps: int = 3
@@ -122,15 +114,15 @@ def init_params(cfg: ModelConfig, seed: int):
 
 
 def make_requests(cfg: ModelConfig, size: SmokeSize, seed: int, n: int,
-                  first_id: int = 0, new_tokens=None) -> List[Request]:
+                  first_id: int = 0) -> List[Request]:
     """Seeded prompts of ``size.prompt_len`` tokens (ids 1..vocab-1)."""
     rng = np.random.default_rng(seed)
     lo, hi = size.prompt_len
     reqs = []
     for i in range(n):
         prompt = rng.integers(1, cfg.vocab_size, int(rng.integers(lo, hi + 1)))
-        budget = size.new_tokens if new_tokens is None else int(new_tokens[i])
-        reqs.append(Request(first_id + i, prompt.tolist(), max_new_tokens=budget))
+        reqs.append(Request(first_id + i, prompt.tolist(),
+                            max_new_tokens=size.new_tokens))
     return reqs
 
 
@@ -152,7 +144,7 @@ def reference_logits(params, tokens: Sequence[int], cfg: ModelConfig):
     precision: no cache, no kernels, no batching.  (T, vocab)."""
     cfg32 = dataclasses.replace(cfg, param_dtype="float32",
                                 compute_dtype="float32",
-                                logit_dtype="float32", attn_impl="ref")
+                                logit_dtype="float32")
     p32 = jax.tree.map(lambda x: x.astype(jnp.float32), params)
     with jax.default_matmul_precision("highest"):
         return _full_forward_logits(p32, jnp.asarray([tokens], jnp.int32),
@@ -228,43 +220,6 @@ def phase_logits(engine: ServeEngine, size: SmokeSize, seed: int) -> Dict:
     if control <= LOGITS_TOL:
         raise AssertionError(f"logits: {CONTROL_MANTISSA_BITS}-bit weights "
                              f"pass the tolerance ({control}); it is too loose")
-    return out
-
-
-def phase_kernel(engine: ServeEngine, size: SmokeSize, seed: int) -> Dict:
-    """Run mixed traffic until the slots hold caches of different lengths,
-    then run one decode step both ways on that same cache.  Leaves the
-    engine mid-traffic."""
-    rng = np.random.default_rng(seed + 2)
-    budgets = rng.integers(16, size.kernel_steps, 3 * len(engine.slots))
-    for r in make_requests(engine.cfg, size, seed + 3, len(budgets),
-                           first_id=20_000, new_tokens=budgets):
-        engine.submit(r)
-    for _ in range(size.kernel_steps):
-        engine.step()
-    live = [i for i, r in enumerate(engine.slots) if r is not None]
-    tokens = jax.device_put(engine._slot_tokens(), engine.device)
-    args = (engine.params, engine.cache, tokens)
-    ref_step = jax.jit(make_decode_step(engine.cfg)).lower(*args).compile()
-    t0 = time.perf_counter()
-    flash_cfg = dataclasses.replace(engine.cfg, attn_impl="flash_decode")
-    flash_step = jax.jit(make_decode_step(flash_cfg)).lower(*args).compile()
-    compile_s = time.perf_counter() - t0
-    native = "tpu_custom_call" in flash_step.as_text()
-    want = ref_step(*args)[1][live, 0]
-    got = flash_step(*args)[1][live, 0]
-    err = max_rel_err(got, want)
-    lens = np.asarray(engine.cache["index"])[live]
-    out = {"slots": len(live), "kv_len_min": int(lens.min()),
-           "kv_len_max": int(lens.max()), "max_rel_err": err,
-           "tol": KERNEL_TOL, "tpu_custom_call": native,
-           "compile_s": compile_s}
-    _log("kernel", **out)
-    if native != (engine.device.platform == "tpu"):
-        raise AssertionError(f"kernel: tpu_custom_call={native} on "
-                             f"{engine.device.platform}")
-    if not np.isfinite(err) or err > KERNEL_TOL:
-        raise AssertionError(f"kernel: flash_decode vs ref rel err {err}")
     return out
 
 
@@ -419,8 +374,6 @@ def serve_phases(cfg: ModelConfig, size: SmokeSize, seed: int,
         phase_serve(a, size, seed)
         phase_logits(a, size, seed)
     phase_kv_ship(a, engine(devices[1]), size, seed)
-    if one_device:
-        phase_kernel(a, size, seed)
 
 
 def main(argv=None) -> int:

@@ -1,7 +1,8 @@
-"""Pallas TPU kernels for the perf-critical compute hot spots.
+"""Pallas TPU kernels for the model's hot spots: decode attention over the
+stacked KV cache (`decode_attention.py`) and the Mamba2 chunked scan
+(`ssm_scan.py`).
 
-Each kernel: <name>.py (pl.pallas_call + explicit BlockSpec VMEM tiling),
-with `ops.py` jit'd wrappers (interpret-mode fallback off-TPU) and `ref.py`
-pure-jnp oracles.  tests/test_kernels.py sweeps shapes/dtypes and asserts
-allclose against the oracles.
+Each kernel is a ``pl.pallas_call`` with explicit VMEM tiling; `ops.py`
+holds the entry points the model calls and `ref.py` the pure-jnp oracles
+that tests/test_kernels.py checks them against.
 """

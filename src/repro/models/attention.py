@@ -1,10 +1,11 @@
 """Grouped-query attention with RoPE/M-RoPE, KV cache, and cross-attention.
 
-The jnp path here is the *reference* implementation (and what the dry-run
-lowers — XLA-native ops give clean HLO for the roofline analysis).  The
-Pallas flash kernels in `repro.kernels` are drop-in replacements selected
-with ``impl="flash"`` / ``impl="flash_decode"`` (validated in interpret mode
-on CPU; TPU is the target).
+One path per mode.  Training, prefill and cross-attention run XLA ops:
+`gqa_reference` below ``CHUNKED_ATTN_THRESHOLD``, above it the
+online-softmax `flash_attention_jnp` (with its own flash backward) or,
+for traced offsets and lengths, `chunked_attention`.  Cached decode
+(S == 1) calls `repro.kernels.ops.stacked_decode_attention`: the Pallas
+kernel when lowered for a TPU, its XLA oracle elsewhere.
 """
 
 from __future__ import annotations
@@ -131,8 +132,7 @@ def gqa_reference(
 
 def _flash_fwd_math(q, k, v, causal, q_offset, kv_len, q_chunk, k_chunk):
     """Online-softmax forward.  q: (B,Sq,Hq,Dh) → (out, lse (B,kv,G,Sq)).
-    Pure XLA ops — `repro.kernels.flash_attention` is the Pallas twin with
-    explicit VMEM tiling."""
+    Pure XLA ops, one (q_chunk × k_chunk) score block at a time."""
     B, Sq, Hq, Dh = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]
     G = Hq // Hkv
@@ -310,7 +310,6 @@ def attention(
     cache: Optional[Dict] = None,
     cache_index: Optional[jnp.ndarray] = None,  # write offset: scalar or (B,)
     cache_layer: Optional[jnp.ndarray] = None,  # layer of a stacked cache
-    impl: Optional[str] = None,
     rope_cache=None,
     kv_len: Optional[jnp.ndarray] = None,  # positions read; cache_index + S
 ) -> Tuple[jnp.ndarray, Optional[Dict]]:
@@ -323,7 +322,6 @@ def attention(
         the valid prefix (S == 1 decode, or S > 1 prefill-into-cache).
       * cross: ``kv_input`` given (no cache, no causality).
     """
-    impl = impl or cfg.attn_impl
     cd = dtype_of(cfg.compute_dtype)
     B, S, _ = x.shape
     kv_src = x if kv_input is None else kv_input
@@ -354,16 +352,11 @@ def attention(
         if S == 1:
             from repro.kernels import ops as kops
             with jax.named_scope(ATTN_SCOPE):
-                if impl == "flash_decode":
-                    out = kops.decode_attention(
-                        q, kv_heads(k_cache, cache_layer, cfg.d_head),
-                        kv_heads(v_cache, cache_layer, cfg.d_head), kv_len)
-                elif cache_layer is None:
-                    out = kops.stacked_decode_attention(
-                        q, k_cache[None], v_cache[None], 0, kv_len)
-                else:
-                    out = kops.stacked_decode_attention(
-                        q, k_cache, v_cache, cache_layer, kv_len)
+                if cache_layer is None:  # one layer's leaves: a stack of one
+                    k_cache, v_cache, cache_layer = (k_cache[None],
+                                                     v_cache[None], 0)
+                out = kops.stacked_decode_attention(
+                    q, k_cache, v_cache, cache_layer, kv_len)
         else:
             # Prefill-into-cache: causal with absolute offset.
             out = _self_attention_math(
@@ -371,11 +364,7 @@ def attention(
                 kv_heads(v_cache, cache_layer, cfg.d_head), causal=True,
                 q_offset=cache_index, kv_len=kv_len)
     else:
-        if impl == "flash" and kv_input is None and causal:
-            from repro.kernels import ops as kops
-            out = kops.flash_attention(q, k, v, causal=True)
-        else:
-            out = _self_attention_math(q, k, v, causal=causal and kv_input is None)
+        out = _self_attention_math(q, k, v, causal=causal and kv_input is None)
 
     out = constrain(out, ("dp", None, "tp", None))
     out = out.reshape(B, S, cfg.n_heads * cfg.d_head)

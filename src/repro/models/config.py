@@ -77,7 +77,6 @@ class ModelConfig:
     remat: str = "block"            # none | block (checkpoint each layer)
     scan_layers: bool = True        # lax.scan over uniform layer stacks
     optimizer: str = "adamw"        # adamw | adafactor | adam8bit
-    attn_impl: str = "ref"          # ref | flash | flash_decode (Pallas)
     ssm_impl: str = "ref"           # ref | pallas
     bf16_cotangent: bool = False    # §Perf: cast backward activations to bf16
     hoist_rope: bool = False        # §Perf: compute RoPE tables once per step

@@ -1,5 +1,6 @@
-"""Per-kernel allclose sweeps: Pallas (interpret mode on CPU) vs jnp oracle,
-across shapes and dtypes, plus hypothesis property tests on invariants."""
+"""Allclose sweeps across shapes and dtypes, plus hypothesis property tests
+on invariants: the Pallas kernels (interpret mode on CPU) against their jnp
+oracles, and the model's XLA attention and norm against references."""
 
 import jax
 import jax.numpy as jnp
@@ -9,8 +10,13 @@ from hypothesis import given, settings, strategies as st
 
 from repro.kernels import decode_attention as _decode
 from repro.kernels import ops, ref
+from repro.models.attention import (chunked_attention, flash_attention_jnp,
+                                    gqa_reference)
+from repro.models.layers import rms_norm
 
 KEY = jax.random.PRNGKey(0)
+EPS = 1e-5
+_flash = jax.jit(flash_attention_jnp, static_argnums=(3, 4, 5))
 
 
 def _rand(shape, dtype, k):
@@ -23,6 +29,11 @@ def _tol(dtype):
 
 
 class TestFlashAttention:
+    """The XLA online-softmax attention that training and long prefill run,
+    at query/key chunks of ``bq``/``bk``: `flash_attention_jnp` (causal, as
+    training calls it) and `chunked_attention` (non-causal), against
+    `gqa_reference`."""
+
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     @pytest.mark.parametrize("B,S,Hq,Hkv,D,bq,bk", [
         (1, 128, 4, 4, 64, 64, 64),     # MHA
@@ -35,8 +46,12 @@ class TestFlashAttention:
         q = _rand((B, S, Hq, D), dtype, 1)
         k = _rand((B, S, Hkv, D), dtype, 2)
         v = _rand((B, S, Hkv, D), dtype, 3)
-        out = ops.flash_attention(q, k, v, causal=causal, block_q=bq, block_k=bk)
-        want = ref.flash_attention_ref(q, k, v, causal=causal)
+        if causal:
+            out = _flash(q, k, v, True, bq, bk)
+        else:
+            out = chunked_attention(q, k, v, causal=False, q_chunk=bq,
+                                    k_chunk=bk)
+        want = gqa_reference(q, k, v, causal)
         np.testing.assert_allclose(np.asarray(out, np.float32),
                                    np.asarray(want, np.float32), **_tol(dtype))
 
@@ -44,40 +59,29 @@ class TestFlashAttention:
         q = _rand((1, 256, 4, 64), jnp.float32, 4)
         k = _rand((1, 256, 2, 64), jnp.float32, 5)
         v = _rand((1, 256, 2, 64), jnp.float32, 6)
-        outs = [np.asarray(ops.flash_attention(q, k, v, block_q=bq, block_k=bk))
+        outs = [np.asarray(_flash(q, k, v, True, bq, bk))
                 for bq, bk in [(64, 64), (128, 64), (256, 128), (256, 256)]]
         for o in outs[1:]:
             np.testing.assert_allclose(o, outs[0], atol=1e-5, rtol=1e-5)
 
+    @pytest.mark.parametrize("causal", [True, False])
+    def test_gradients_match_ref(self, causal):
+        """The hand-written flash backward (`_flash_bwd_rule`) gives the
+        gradients of the reference for q, k and v."""
+        q = _rand((2, 256, 8, 64), jnp.float32, 7)
+        k = _rand((2, 256, 2, 64), jnp.float32, 8)
+        v = _rand((2, 256, 2, 64), jnp.float32, 9)
+        w = _rand((2, 256, 8, 64), jnp.float32, 10)
 
-class TestDecodeAttention:
-    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-    @pytest.mark.parametrize("B,Sk,Hq,Hkv,D,bk", [
-        (1, 256, 4, 4, 64, 64),
-        (2, 512, 8, 2, 64, 128),
-        (3, 384, 6, 6, 32, 128),
-    ])
-    def test_matches_ref(self, B, Sk, Hq, Hkv, D, bk, dtype):
-        q = _rand((B, 1, Hq, D), dtype, 7)
-        k = _rand((B, Sk, Hkv, D), dtype, 8)
-        v = _rand((B, Sk, Hkv, D), dtype, 9)
-        kv_len = jnp.arange(1, B + 1, dtype=jnp.int32) * (Sk // (B + 1))
-        out = ops.decode_attention(q, k, v, kv_len, block_k=bk)
-        want = ref.decode_attention_ref(q, k, v, kv_len)
-        np.testing.assert_allclose(np.asarray(out, np.float32),
-                                   np.asarray(want, np.float32), **_tol(dtype))
-
-    def test_stale_cache_is_masked(self):
-        """Entries past kv_len must not affect the output."""
-        q = _rand((1, 1, 4, 32), jnp.float32, 10)
-        k = _rand((1, 128, 4, 32), jnp.float32, 11)
-        v = _rand((1, 128, 4, 32), jnp.float32, 12)
-        kv_len = jnp.array([64], jnp.int32)
-        out1 = ops.decode_attention(q, k, v, kv_len, block_k=64)
-        k2 = k.at[:, 64:].set(999.0)
-        v2 = v.at[:, 64:].set(-999.0)
-        out2 = ops.decode_attention(q, k2, v2, kv_len, block_k=64)
-        np.testing.assert_allclose(np.asarray(out1), np.asarray(out2))
+        def grads(attend):
+            return jax.jit(jax.grad(
+                lambda q, k, v: jnp.sum(attend(q, k, v) * w),
+                argnums=(0, 1, 2)))(q, k, v)
+        got = grads(lambda q, k, v: _flash(q, k, v, causal, 64, 128))
+        want = grads(lambda q, k, v: gqa_reference(q, k, v, causal))
+        for g, r in zip(got, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                       **_tol(jnp.float32))
 
 
 class TestStackedDecodeAttention:
@@ -91,6 +95,10 @@ class TestStackedDecodeAttention:
         (2, 2, 64, 8, 1, 128, 1 << 20),     # MQA, one head per tile
         (2, 2, 32, 6, 3, 32, 1 << 20),      # heads of 32, not lane-aligned
         (1, 2, 64, 4, 1, 64, 1 << 20),      # one head narrower than a tile
+        # Blocks of 64 / 128 / 96 positions in bf16 (half that in f32):
+        (2, 1, 256, 4, 4, 64, 64 * 256 * 2),    # MHA
+        (2, 2, 512, 8, 2, 64, 128 * 128 * 2),   # GQA 4:1
+        (2, 3, 384, 6, 6, 32, 128 * 192 * 2),   # heads of 32, six KV heads
     ])
     def test_matches_ref(self, L, B, Sk, Hq, Hkv, D, block_bytes, dtype):
         q = _rand((B, 1, Hq, D), dtype, 20)
@@ -130,12 +138,13 @@ class TestStackedDecodeAttention:
         np.testing.assert_array_equal(np.asarray(out[0], np.float32), 0.0)
         np.testing.assert_array_equal(np.asarray(want[0], np.float32), 0.0)
 
-    def test_other_layers_and_stale_rows_are_not_read(self):
+    @pytest.mark.parametrize("Hq,Hkv", [(4, 2), (4, 4)], ids=["gqa", "mha"])
+    def test_other_layers_and_stale_rows_are_not_read(self, Hq, Hkv):
         """Rows past each length, whole free slots and the other layers
         hold NaN: the output stays finite and the same."""
-        q = _rand((3, 1, 4, 64), jnp.float32, 23)
-        k = _rand((3, 3, 128, 128), jnp.float32, 24)
-        v = _rand((3, 3, 128, 128), jnp.float32, 25)
+        q = _rand((3, 1, Hq, 64), jnp.float32, 23)
+        k = _rand((3, 3, 128, Hkv * 64), jnp.float32, 24)
+        v = _rand((3, 3, 128, Hkv * 64), jnp.float32, 25)
         lens = [40, 0, 97]
         kv_len = jnp.array(lens, jnp.int32)
 
@@ -164,15 +173,20 @@ class TestStackedDecodeAttention:
 
 
 class TestRmsNorm:
+    """`models.layers.rms_norm`, the norm every layer runs, against the
+    formula in float64."""
+
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     @pytest.mark.parametrize("shape", [(4, 128), (2, 37, 256), (1, 5, 7, 64), (300, 512)])
     def test_matches_ref(self, shape, dtype):
         x = _rand(shape, dtype, 13)
         scale = _rand((shape[-1],), dtype, 14)
-        out = ops.rms_norm(x, scale)
-        want = ref.rms_norm_ref(x, scale)
-        np.testing.assert_allclose(np.asarray(out, np.float32),
-                                   np.asarray(want, np.float32), **_tol(dtype))
+        out = rms_norm(x, scale, EPS)
+        x64 = np.asarray(x, np.float64)
+        want = (x64 / np.sqrt(np.mean(x64 ** 2, axis=-1, keepdims=True) + EPS)
+                * np.asarray(scale, np.float64))
+        np.testing.assert_allclose(np.asarray(out, np.float32), want,
+                                   **_tol(dtype))
 
     @given(rows=st.integers(1, 64), d=st.sampled_from([32, 64, 128]),
            seed=st.integers(0, 100))
@@ -181,8 +195,8 @@ class TestRmsNorm:
         """rms_norm(c·x) == rms_norm(x) for any c > 0 (scale invariance)."""
         x = jax.random.normal(jax.random.PRNGKey(seed), (rows, d))
         scale = jnp.ones((d,))
-        a = np.asarray(ops.rms_norm(x, scale))
-        b = np.asarray(ops.rms_norm(3.7 * x, scale))
+        a = np.asarray(rms_norm(x, scale, EPS))
+        b = np.asarray(rms_norm(3.7 * x, scale, EPS))
         np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
 
 

@@ -17,8 +17,6 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels import decode_attention as _decode
-from repro.kernels import flash_attention as _flash
-from repro.kernels import rmsnorm as _rmsnorm
 from repro.kernels import ssm_scan as _ssm
 from repro.models import init_cache, init_lm
 from repro.serve.engine import make_decode_step
@@ -56,22 +54,12 @@ def _compile(fn, shapes, sharding):
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
 
-@pytest.mark.parametrize("B,Sk,Hq,Hkv,D", [
-    (16, 4096, QWEN.n_heads, QWEN.n_kv_heads, QWEN.d_head),   # qwen decode
-    (8, 4096, 32, 4, 128),                                     # GQA, G = 8
-])
-def test_decode_attention_compiles(one_chip, B, Sk, Hq, Hkv, D):
-    hlo = _compile(
-        lambda q, k, v, n: _decode.decode_attention(q, k, v, n, interpret=False),
-        [((B, 1, Hq, D), BF16), ((B, Sk, Hkv, D), BF16),
-         ((B, Sk, Hkv, D), BF16), ((B,), I32)], one_chip)
-    assert "tpu_custom_call" in hlo
-
-
 @pytest.mark.parametrize("L,B,Sk,Hq,Hkv,D", [
     (40, 32, 2048, 32, 8, 64),      # granite: two heads per 128-lane tile
     (24, 32, 2048, 16, 16, 64),     # qwen, MHA
     (4, 8, 4096, 32, 4, 128),       # one head per tile, two key blocks
+    (1, 16, 4096, 16, 16, 64),      # one layer, qwen's heads, 4096 positions
+    (1, 8, 4096, 32, 4, 128),       # one layer, GQA with G = 8
 ])
 def test_stacked_decode_attention_compiles(one_chip, L, B, Sk, Hq, Hkv, D):
     hlo = _compile(
@@ -148,21 +136,6 @@ def test_decode_step_keeps_the_stacked_cache_in_place(one_chip, cfg):
         a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
     assert memory.temp_size_in_bytes < layer * 2    # one layer's K, bf16
     assert "stacked_decode_attention" in hlo and "tpu_custom_call" in hlo
-
-
-def test_flash_attention_compiles(one_chip):
-    qkv = ((1, 2048, QWEN.n_heads, QWEN.d_head), BF16)
-    hlo = _compile(
-        lambda q, k, v: _flash.flash_attention(q, k, v, interpret=False),
-        [qkv, qkv, qkv], one_chip)
-    assert "tpu_custom_call" in hlo
-
-
-def test_rms_norm_compiles(one_chip):
-    hlo = _compile(lambda x, s: _rmsnorm.rms_norm(x, s, interpret=False),
-                   [((4096, QWEN.d_model), BF16), ((QWEN.d_model,), BF16)],
-                   one_chip)
-    assert "tpu_custom_call" in hlo
 
 
 def test_ssm_scan_compiles(one_chip):
