@@ -3,6 +3,12 @@
 `make_prefill_step` / `make_decode_step` are the pjit-able pure functions the
 dry-run lowers for the prefill_32k / decode_32k / long_500k cells; the
 `ServeEngine` drives them for real requests (examples/serve_lm.py).
+
+The engine keeps one decode step in flight: each `ServeEngine.step` call
+dispatches step n+1 before it reads step n's tokens.  `make_serve_step`
+wraps the decode step with the token choice and the sampling, so a slot
+that is generating is fed step n's sampled token without it leaving the
+device; the host reads each step's tokens once, one step behind.
 """
 
 from __future__ import annotations
@@ -85,10 +91,54 @@ def make_decode_step(cfg: ModelConfig):
     return decode
 
 
-def sample(logits: jnp.ndarray, key, temperature: float = 0.0) -> jnp.ndarray:
+def request_key(base_key, req_id, generated):
+    """Sampling key of a request's next token: derived from (req_id,
+    tokens generated so far), never from batch position or step count —
+    so a sampled decode replays identically whatever other requests
+    share the batch, and a request resumed on another engine (same
+    ``rng_seed``) continues the same stream."""
+    return jax.random.fold_in(jax.random.fold_in(base_key, req_id), generated)
+
+
+def sample(logits: jnp.ndarray, keys=None,
+           temperature: float = 0.0) -> jnp.ndarray:
+    """Each row's next token of ``logits`` (rows, vocab): the best at
+    temperature 0, else drawn with that row's key (``keys``, one a row)."""
     if temperature <= 0.0:
         return jnp.argmax(logits, axis=-1)
-    return jax.random.categorical(key, logits / temperature, axis=-1)
+    return jax.vmap(lambda key, row: jax.random.categorical(
+        key, row / temperature, axis=-1))(keys, logits)
+
+
+#: Rows of the array `make_serve_step` is fed each step, one column a slot.
+FEED_TOKEN, FEED_FROM_DEVICE, FEED_REQ_ID, FEED_GENERATED = range(4)
+
+
+def make_serve_step(cfg: ModelConfig, temperature: float = 0.0,
+                    rng_seed: int = 0):
+    """(params, cache, feed (4,B) int32, prev (B,) int32) ->
+    (cache, logits (B,1,V), next (B,) int32): `make_decode_step` with the
+    tokens chosen and sampled on the device.
+
+    A slot is fed ``prev[b]`` (the previous step's ``next``) where
+    ``feed[FEED_FROM_DEVICE, b]`` is set, else ``feed[FEED_TOKEN, b]``
+    (``NO_TOKEN`` for a slot that holds no request).  ``next`` is each
+    slot's sampled token, its key folded from ``feed[FEED_REQ_ID]`` and
+    ``feed[FEED_GENERATED]`` as `request_key` folds it."""
+    decode = make_decode_step(cfg)
+    base_key = jax.random.PRNGKey(rng_seed)
+
+    def serve(params, cache, feed, prev):
+        tokens = jnp.where(feed[FEED_FROM_DEVICE] > 0, prev, feed[FEED_TOKEN])
+        cache, logits = decode(params, cache, tokens[:, None])
+        keys = None
+        if temperature > 0.0:
+            keys = jax.vmap(functools.partial(request_key, base_key))(
+                feed[FEED_REQ_ID], feed[FEED_GENERATED])
+        return cache, logits, sample(logits[:, 0], keys,
+                                     temperature).astype(jnp.int32)
+
+    return serve
 
 
 # ------------------------------------------------------------------ engine
@@ -104,13 +154,32 @@ class Request:
     t_first: Optional[float] = dataclasses.field(default=None, compare=False)
 
 
+@dataclasses.dataclass
+class _Step:
+    """One launched decode step, until its tokens are emitted."""
+    feed: np.ndarray                 # (4, B) int32, as `make_serve_step` takes it
+    reqs: List[Optional[Request]]    # the request each slot was fed for
+    gen: np.ndarray                  # (B,) bool: the slot's token is generated
+    pos: np.ndarray                  # (B,) the position each slot writes
+    fed: Optional[np.ndarray] = None     # (B,) bool: ``reqs[b]`` is set
+    logits: Optional[jax.Array] = None
+    tokens: Optional[jax.Array] = None   # (B,) int32, on the device
+    host: Optional[np.ndarray] = None    # ``tokens`` once read
+
+
 class ServeEngine:
     """Slot-based continuous batching over a fixed decode batch.
 
-    Finished sequences free their slot; queued requests are prefilling into
-    freed slots (stop-the-world prefill — adequate for the example driver;
-    the scheduler-level placement of *engines* is what the paper's technique
-    manages, see `core.cluster`).
+    Finished sequences free their slot; queued requests take freed slots
+    and feed their prompts through the decode step, one token a step
+    beside the slots that generate (the scheduler-level placement of
+    *engines* is what the paper's technique manages, see `core.cluster`).
+
+    One step stays in flight: `step` dispatches the next decode before it
+    reads the tokens of the one before, and a generating slot is fed its
+    previous token on the device.  `export_slot` first brings its slot
+    level with the device, and `run_until_done` returns with nothing in
+    flight.
 
     Params and cache live on ``device`` (default ``jax.devices()[0]``); the
     decode step donates the cache, so only one copy of it is ever live."""
@@ -130,26 +199,21 @@ class ServeEngine:
         self.cache = jax.jit(
             lambda: init_cache(cfg, batch_slots, max_len, per_slot_index=True),
             out_shardings=jax.sharding.SingleDeviceSharding(self.device))()
-        # Per-slot write offsets (slot-local KV positions).
+        # Per-slot write offsets (slot-local KV positions) of every step
+        # launched, the one in flight included.
         self.offsets = np.zeros(batch_slots, np.int32)
-        self._decode = jax.jit(make_decode_step(cfg), donate_argnums=(1,))
+        self._decode = jax.jit(make_serve_step(cfg, temperature, rng_seed),
+                               donate_argnums=(1,))
+        self._no_tokens = jax.device_put(np.zeros(batch_slots, np.int32),
+                                         self.device)
+        self._flight: Optional[_Step] = None
         self._slot_bytes = sum(
             int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(
                 jax.eval_shape(read_slot, self.cache, 0)))
         # Positions decode attention copies at a time (`kv_block`).
         self._kv_block = kv_block(max_len, cfg.n_kv_heads * cfg.d_head
                                   * np.dtype(dtype_of(cfg.compute_dtype)).itemsize)
-        self._base_key = jax.random.PRNGKey(rng_seed)
         self.steps = 0
-
-    def _request_key(self, req: Request):
-        """Sampling key for ``req``'s next token: derived from (req_id,
-        tokens generated so far), never from batch position or step count —
-        so a sampled decode replays identically whatever other requests
-        share the batch, and a request resumed on another engine (same
-        ``rng_seed``) continues the same stream."""
-        return jax.random.fold_in(
-            jax.random.fold_in(self._base_key, req.req_id), len(req.output))
 
     def submit(self, req: Request) -> None:
         self.queue.append(req)
@@ -166,22 +230,69 @@ class ServeEngine:
         req.output = []
         req.t_admit, req.t_first = time.perf_counter(), None
 
-    def _slot_tokens(self) -> np.ndarray:
-        toks = np.full((len(self.slots), 1), NO_TOKEN, np.int32)
+    def _ends(self, req: Request, generated: int, written: int) -> bool:
+        """Whether ``req`` stops by length with ``generated`` tokens and
+        ``written`` positions: known before the last token is read."""
+        return (generated >= req.max_new_tokens
+                or written >= self.max_len - 1)
+
+    def _plan(self, prev: Optional[_Step]) -> Optional[_Step]:
+        """The step after ``prev``: what each slot is fed, with the fed
+        slots' offsets advanced; None when no slot is fed.
+
+        A slot whose token of ``prev`` is a generated one, not yet read, is
+        fed it on the device, unless the request ends with that token by
+        length.  Every other slot that holds a request is fed from the
+        host: a prompt token, or its last token when no step in flight
+        holds it (a slot just imported, or read by `export_slot`)."""
+        n = len(self.slots)
+        feed = np.zeros((4, n), np.int32)
+        feed[FEED_TOKEN] = NO_TOKEN
+        step = _Step(feed, [None] * n, np.zeros(n, bool), self.offsets.copy())
         for i, req in enumerate(self.slots):
             if req is None:
                 continue
-            pos = int(self.offsets[i])
-            if pos < len(req.prompt):
-                toks[i, 0] = req.prompt[pos]
+            if req.done:              # ended while a move read its last token
+                self.slots[i] = None
+                continue
+            p = int(step.pos[i])
+            pending = prev is not None and prev.reqs[i] is req and prev.gen[i]
+            generated = len(req.output) + pending
+            if pending and self._ends(req, generated, p):
+                continue
+            if p < len(req.prompt):
+                feed[FEED_TOKEN, i] = req.prompt[p]
+            elif pending:
+                feed[FEED_FROM_DEVICE, i] = 1
             else:
-                toks[i, 0] = req.output[-1] if req.output else self.eos_id
-        return toks
+                feed[FEED_TOKEN, i] = req.output[-1] if req.output else self.eos_id
+            feed[FEED_REQ_ID, i], feed[FEED_GENERATED, i] = req.req_id, generated
+            step.reqs[i], step.gen[i] = req, p >= len(req.prompt) - 1
+        step.fed = np.array([r is not None for r in step.reqs])
+        if not step.fed.any():
+            return None
+        self.offsets[step.fed] += 1
+        return step
+
+    @staticmethod
+    def _read(step: _Step) -> np.ndarray:
+        """``step``'s tokens on the host: waits for the step, once."""
+        if step.host is None:
+            step.host = np.asarray(step.tokens)
+        return step.host
 
     def step(self) -> Optional[jax.Array]:
-        """Admit queued requests into free slots and decode one token for
-        every occupied slot.  Returns the step's logits (slots, 1, vocab),
-        or None when there was nothing to decode."""
+        """Admit queued requests into free slots, launch the next decode
+        step, then read and emit the step launched before it.  Returns the
+        emitted step's logits (slots, 1, vocab), or None when nothing was
+        held or in flight.
+
+        Each call emits exactly one step's tokens.  With a step already in
+        flight (n), the call launches n+1, then waits for n; with none, it
+        launches n and n+1 back to back and waits for n.  A slot that ends
+        by length at n is not fed at n+1; one that stops on ``eos_id`` is
+        learned at n's emit, and its token of n+1 is discarded.  Tokens go
+        to the request each slot held when the step was launched."""
         dev = self.device.id
         with span("serve.step", device=dev):
             free = [i for i, s in enumerate(self.slots) if s is None]
@@ -189,57 +300,77 @@ class ServeEngine:
                 with span("serve.admit", device=dev):
                     for i in free[:len(self.queue)]:
                         self._admit(i, self.queue.pop(0))
-            if all(s is None for s in self.slots):
+            prev = self._flight
+            if prev is None and all(s is None for s in self.slots):
                 return None
             with span("serve.feed", device=dev):
-                host_tokens = self._slot_tokens()
-                tokens = jax.device_put(host_tokens, self.device)
-            live = host_tokens[:, 0] != NO_TOKEN
-            blocks = -(-(self.offsets[live] + 1) // self._kv_block)
-            with span("serve.launch", device=dev, live=int(live.sum()),
-                      kv_positions=int(blocks.sum()) * self._kv_block):
-                self.cache, logits = self._decode(self.params, self.cache,
-                                                  tokens)
-            self.steps += 1
+                launch = []
+                nxt = self._plan(prev)
+                if nxt is not None:
+                    launch.append(nxt)
+                    if prev is None:
+                        after = self._plan(nxt)
+                        if after is not None:
+                            launch.append(after)
+                feeds = jax.device_put([s.feed for s in launch], self.device)
+            blocks = sum(int((-(-(s.pos[s.fed] + 1) // self._kv_block)).sum())
+                         for s in launch)
+            with span("serve.launch", device=dev,
+                      live=sum(int(s.fed.sum()) for s in launch),
+                      kv_positions=blocks * self._kv_block,
+                      ahead=int(prev is not None and bool(launch))):
+                last = prev
+                for s, feed in zip(launch, feeds):
+                    self.cache, s.logits, s.tokens = self._decode(
+                        self.params, self.cache, feed,
+                        last.tokens if last is not None else self._no_tokens)
+                    last = s
+            steps = ([prev] if prev is not None else []) + launch
+            if not steps:
+                return None
+            done = steps[0]
+            self._flight = steps[1] if len(steps) > 1 else None
             with span("serve.wait", device=dev):
-                next_tok = self._next_tokens(logits)
-            with span("serve.emit", device=dev):
-                self._emit(next_tok)
-            return logits
+                tokens = self._read(done)
+            with span("serve.emit", device=dev) as emit:
+                emit.set_metadata(discarded=self._emit(done, tokens))
+            self.steps += 1
+            return done.logits
 
-    def _next_tokens(self, logits: jax.Array) -> np.ndarray:
-        """Each slot's sampled token, on the host: waits for the step."""
-        if self.temperature <= 0.0:
-            return np.asarray(sample(logits[:, 0], None, 0.0))
-        next_tok = np.zeros(len(self.slots), np.int64)
-        for i, req in enumerate(self.slots):
-            if req is not None:
-                next_tok[i] = int(sample(logits[i, 0], self._request_key(req),
-                                         self.temperature))
-        return next_tok
+    def _give(self, slot: int, req: Request, token: int, written: int) -> None:
+        """Append ``req``'s next token; a finished request frees ``slot``."""
+        req.output.append(token)
+        if req.t_first is None:
+            req.t_first = time.perf_counter()
+        if token == self.eos_id or self._ends(req, len(req.output), written):
+            req.done = True
+            self.finished.append(req)
+            self.slots[slot] = None
 
-    def _emit(self, next_tok: np.ndarray) -> None:
-        """Advance every live slot; a generating one gets its token, and a
-        finished request frees its slot."""
-        for i, req in enumerate(self.slots):
-            if req is None:
+    def _emit(self, step: _Step, tokens: np.ndarray) -> int:
+        """Give each generated token of ``step`` to its request; returns
+        the tokens discarded because their slot had stopped or moved."""
+        discarded = 0
+        for i, req in enumerate(step.reqs):
+            if req is None or not step.gen[i]:
                 continue
-            self.offsets[i] += 1
-            pos = int(self.offsets[i])
-            if pos >= len(req.prompt):  # generating
-                req.output.append(int(next_tok[i]))
-                if req.t_first is None:
-                    req.t_first = time.perf_counter()
-                if (len(req.output) >= req.max_new_tokens
-                        or int(next_tok[i]) == self.eos_id
-                        or pos >= self.max_len - 1):
-                    req.done = True
-                    self.finished.append(req)
-                    self.slots[i] = None
+            if req.done or self.slots[i] is not req:
+                discarded += 1
+                continue
+            self._give(i, req, int(tokens[i]), int(step.pos[i]) + 1)
+        return discarded
 
     def run_until_done(self, max_steps: int = 10_000) -> List[Request]:
-        while (self.queue or any(self.slots)) and self.steps < max_steps:
+        """Step until nothing is queued, held or in flight, or for
+        ``max_steps``; a step still in flight is then read and emitted, so
+        every request holds the tokens of every step launched for it."""
+        while ((self.queue or any(s is not None for s in self.slots)
+                or self._flight is not None) and self.steps < max_steps):
             self.step()
+        last, self._flight = self._flight, None
+        if last is not None:
+            self._emit(last, self._read(last))
+            self.steps += 1
         return self.finished
 
     # ---------------------------------------------------- slot migration --
@@ -250,10 +381,21 @@ class ServeEngine:
     # config/params (on this device or another), and decoding continues
     # bit-identically.
     def export_slot(self, slot: int) -> Dict:
-        """Copy out one slot's KV/recurrent state + write offset."""
+        """Copy out one slot's KV/recurrent state + write offset.
+
+        The slot is first brought level with the device: if the step in
+        flight generates a token for the request the slot holds, the call
+        waits for that step and gives the token to the request, so that
+        the offset and the state agree with the tokens it holds; that
+        step's emit then skips the slot."""
         offset = int(self.offsets[slot])
         with span("serve.export", device=self.device.id,
                   bytes=self._slot_bytes, positions=offset):
+            step, req = self._flight, self.slots[slot]
+            if (step is not None and req is not None and not req.done
+                    and step.reqs[slot] is req and step.gen[slot]):
+                step.reqs[slot] = None
+                self._give(slot, req, int(self._read(step)[slot]), offset)
             state = _read_slot(self.cache, slot)
         state["offset"] = offset
         return state
@@ -261,7 +403,9 @@ class ServeEngine:
     def import_slot(self, slot: int, state: Dict) -> None:
         """Install an `export_slot` payload into ``slot`` (overwrites it).
         The payload may come from an engine on another device; it is
-        copied onto this engine's device first."""
+        copied onto this engine's device first.  No step in flight holds
+        the session, so its next step feeds its last token from the
+        host."""
         arrays = {k: v for k, v in state.items() if k != "offset"}
         with span("serve.import", device=self.device.id,
                   bytes=sum(x.nbytes for x in jax.tree.leaves(arrays)),

@@ -19,9 +19,18 @@ from repro.models.attention import ATTN_SCOPE, KV_SCOPE
 
 #: Host spans of ``ServeEngine.step``: the whole step, then its phases.
 #: ``serve.admit`` opens only on steps that give a request a slot.
-#: ``serve.launch`` also carries ``live`` (the slots fed a token) and
-#: ``kv_positions`` (the positions decode attention reads: each live
-#: slot's length, rounded up to the kernel's copy block).
+#: ``serve.feed`` builds the next step's inputs and sends them;
+#: ``serve.launch`` dispatches it, while the step before is still in
+#: flight; ``serve.wait`` then waits for that earlier step and reads its
+#: tokens, and ``serve.emit`` gives them to their requests.  On a call
+#: with no step in flight, feed and launch cover two steps back to back,
+#: and wait reads the first.  ``serve.launch`` also carries ``live`` (the
+#: slots fed a token), ``kv_positions`` (the positions decode attention
+#: reads: each live slot's length, rounded up to the kernel's copy
+#: block), both summed over the steps it dispatches, and ``ahead`` (1
+#: when it dispatched while a step was in flight).  ``serve.emit`` also
+#: carries ``discarded`` (tokens of the step dropped because their slot
+#: had stopped on ``eos_id`` or lost its request since the launch).
 #: ``serve.export`` and ``serve.import`` are a session's move
 #: (``export_slot``, ``import_slot``); they also carry ``bytes`` (the
 #: payload's size) and ``positions`` (the slot's write offset).

@@ -16,7 +16,7 @@ from repro.kernels import decode_attention as _decode
 from repro.kernels import ops
 from repro.models import init_lm, reduced
 from repro.serve import Request, ServeEngine
-from repro.serve.engine import NO_TOKEN
+from repro.serve.engine import FEED_TOKEN, NO_TOKEN
 
 # GQA 4:1 with heads of 64, as granite-3-2b.
 CFG = reduced(get_config("granite-3-2b"), n_heads=8, n_kv_heads=2,
@@ -62,14 +62,16 @@ def test_a_free_slot_keeps_its_offset_and_reads_nothing(params, monkeypatch):
     index = np.asarray(eng.cache["index"])
     assert index[0] == 4 and index[2] == 0       # fed 4 tokens; never used
     for _ in range(3):
+        jax.block_until_ready(eng.cache)         # the step in flight ran
         del seen[:]
-        eng.step()
+        eng.step()                               # launches the next one
+        jax.block_until_ready(eng.cache)
         assert len(seen) == CFG.n_layers
         for lens in seen:
             np.testing.assert_array_equal(lens, [0, eng.offsets[1], 0])
     np.testing.assert_array_equal(np.asarray(eng.cache["index"]),
                                   [4, eng.offsets[1], 0])
-    assert (eng._slot_tokens()[[0, 2], 0] == NO_TOKEN).all()
+    assert (eng._flight.feed[FEED_TOKEN, [0, 2]] == NO_TOKEN).all()
 
 
 def _alone(params, i):
@@ -127,7 +129,7 @@ def test_launch_counts_live_slots_and_positions_read(tmp_path):
     state["offset"], state["index"] = 700, np.int32(700)
     eng.import_slot(2, state)
     eng.slots[2] = Request(0, [1, 2, 3], max_new_tokens=100, output=[3])
-    eng.step()                                   # compiles; offset 701
+    eng.step()                 # compiles; offsets 701 and 702 launched
     eng.submit(Request(1, [4, 5, 6], max_new_tokens=4))
     jax.profiler.start_trace(str(tmp_path))
     eng.step()

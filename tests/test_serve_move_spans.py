@@ -65,7 +65,8 @@ def test_move_spans_name_chip_bytes_and_positions(tmp_path):
     assert {"serve.export", "serve.import"} <= set(SPANS)
     assert set(spans) == {"serve.export", "serve.import"}
     size = _payload_bytes(state)
-    assert size > 0 and state["offset"] == len(PROMPT) + 3
+    # eight steps read, and the one in flight when the move came
+    assert size > 0 and state["offset"] == len(PROMPT) + 4
     for name, eng in (("serve.export", src), ("serve.import", dst)):
         assert int(spans[name]["device"]) == eng.device.id
         assert int(spans[name]["bytes"]) == size

@@ -19,7 +19,7 @@ from repro.configs import get_config
 from repro.kernels import decode_attention as _decode
 from repro.kernels import ssm_scan as _ssm
 from repro.models import init_cache, init_lm
-from repro.serve.engine import make_decode_step
+from repro.serve.engine import make_serve_step
 
 QWEN = get_config("qwen1.5-0.5b")      # 16 heads (kv 16) of 64, d_model 1024
 GRANITE = get_config("granite-3-2b")   # 32 heads (kv 8) of 64, d_model 2048
@@ -108,8 +108,10 @@ def _cache_sized_moves(hlo: str, layer: int):
 @pytest.mark.parametrize("cfg", [GRANITE, QWEN],
                          ids=["granite2b.chat", "qwen05b.move4"])
 def test_decode_step_keeps_the_stacked_cache_in_place(one_chip, cfg):
-    """The continuous-batching decode step at both cells' shapes (published
-    widths and depth, 32 slots x 2048), with the live-prefix kernel: each
+    """The engine's step (`make_serve_step`: the continuous-batching decode
+    step with its tokens chosen and sampled on the device) at both cells'
+    shapes (published widths and depth, 32 slots x 2048), with the
+    live-prefix kernel: each
     layer writes only its new rows into the donated stacked cache, which
     stays in place (aliased to the output), and reads its layer where it
     lies.  No instruction copies, slices or rewrites a layer's K or V
@@ -125,9 +127,10 @@ def test_decode_step_keeps_the_stacked_cache_in_place(one_chip, cfg):
     params = shaped(jax.eval_shape(lambda: init_lm(jax.random.PRNGKey(0), cfg)))
     cache = shaped(jax.eval_shape(
         lambda: init_cache(cfg, B, T, per_slot_index=True)))
-    tokens = jax.ShapeDtypeStruct((B, 1), I32, sharding=one_chip)
-    compiled = jax.jit(make_decode_step(cfg), donate_argnums=(1,)).lower(
-        params, cache, tokens).compile()
+    feed = jax.ShapeDtypeStruct((4, B), I32, sharding=one_chip)
+    prev = jax.ShapeDtypeStruct((B,), I32, sharding=one_chip)
+    compiled = jax.jit(make_serve_step(cfg), donate_argnums=(1,)).lower(
+        params, cache, feed, prev).compile()
     hlo = compiled.as_text()
     layer = B * T * cfg.n_kv_heads * cfg.d_head
     assert _cache_sized_moves(hlo, layer) == []
