@@ -16,12 +16,12 @@ The control is the same reference with its matrix products in float8
 
 from __future__ import annotations
 
+from types import ModuleType
 from typing import Dict, List, Sequence
 
 import jax
 import numpy as np
 
-from chipbench import reference
 from chipbench.serving import ReqRecord
 
 
@@ -43,12 +43,12 @@ def sample(finished: Sequence[ReqRecord], n: int, seed: int,
     return chosen
 
 
-def served_gaps(w: Dict[str, jax.Array], model: Dict, reference_name: str,
+def served_gaps(w: Dict[str, jax.Array], model: Dict, ref: ModuleType,
                 records: Sequence[ReqRecord], max_len: int,
                 control: bool = False) -> Dict[str, np.ndarray]:
-    """Per sampled request: the gap of each served token and, with
-    ``control``, of the float8 pass's first choice."""
-    ref = reference.module(reference_name)
+    """Per sampled request: the gap of each served token by the plain
+    reference ``ref`` and, with ``control``, of the float8 pass's first
+    choice."""
     out = {"program": [], "control": []}
     for r in records:
         seq = list(r.request.prompt) + list(r.request.output)

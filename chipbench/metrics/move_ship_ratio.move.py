@@ -1,12 +1,12 @@
 """Mean over the moves begun in the window of the bytes shipped over the
-bytes of the session's live keys and values (its positions so far)."""
+bytes of the session's live state at its positions so far, as the cell's
+architecture counts it (a dense decoder's: its keys and values)."""
 
-from chipbench.counts import kv_bytes_per_position
 from chipbench.readings import window_moves
 
 
 def read(run):
-    per = kv_bytes_per_position(run.model)
-    ratios = [m.shipped_bytes / (m.live_positions * per)
+    ratios = [m.shipped_bytes
+              / run.cell.arch.session_bytes(run.model, m.live_positions)
               for m in window_moves(run) if m.live_positions]
     return sum(ratios) / len(ratios) if ratios else None

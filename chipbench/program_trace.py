@@ -328,11 +328,10 @@ def decode_hlo(cell, device) -> str:
     import jax
     import jax.numpy as jnp
 
-    from chipbench import program
     from repro.models import init_cache, init_lm
     from repro.serve.engine import make_decode_step
 
-    cfg = program.model_config(cell.config)
+    cfg = cell.arch.model_config(cell.config)
     e = cell.config["engine"]
     one = jax.sharding.SingleDeviceSharding(device)
 

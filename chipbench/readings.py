@@ -86,7 +86,7 @@ def traced_work(run: RunRecord):
     flops = least = 0.0
     steps = memory_bound = 0
     for s in window_steps(run, traced=True):
-        f, b = counts.step_work(run.model, s.contexts)
+        f, b = run.cell.arch.step_work(run.model, s.contexts)
         t, bound = counts.least_time(f, b, run.peak)
         flops, least = flops + f, least + t
         steps += 1

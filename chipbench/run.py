@@ -110,14 +110,14 @@ def _trace_summary(devices) -> trace.Summary:
 def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
              devices: Sequence, control: bool = False) -> Dict:
     """One run of ``cell`` on ``devices``; the result as a dict."""
-    mix, config = cell.traffic, cell.config
+    mix, config, arch = cell.traffic, cell.config, cell.arch
     model = config["model"]
     replicas_n = int(mix.get("replicas", 1))
-    cfg = program.model_config(config)
+    cfg = arch.model_config(config)
 
     t = time.perf_counter()
-    w = weights.generate(model, seed, devices[0])
-    params = program.program_params(w, cfg)
+    w = weights.generate(arch, model, seed, devices[0])
+    params = program.params(arch, w, cfg)
     jax.block_until_ready(params)
     log(f"weights: {sum(x.size for x in w.values())} parameters on "
         f"{devices[0]} in {time.perf_counter() - t:.3f} s")
@@ -202,8 +202,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
     del replicas, runner, rep
     gc.collect()
     t = time.perf_counter()
-    w = weights.generate(model, seed, devices[0])
-    gaps = check.served_gaps(w, model, config["reference"], chosen,
+    w = weights.generate(arch, model, seed, devices[0])
+    gaps = check.served_gaps(w, model, cell.reference, chosen,
                              int(config["engine"]["max_len"]), control)
     del w
     served = sum(g.size for g in gaps["program"])

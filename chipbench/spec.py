@@ -1,9 +1,10 @@
 """Find a cell and everything it names by file name.
 
 A cell is an entry of ``BENCHMARK.json``'s ``workloads``.  Its
-configuration, traffic mix, fixed rate, limits and metric readers each sit
-in a file of their own under ``<root>/chipbench/``, so a later cell, mix,
-configuration or metric is added as new files and entries only.
+configuration, traffic mix, fixed rate, limits, architecture module, plain
+reference and metric readers each sit in a file of their own under
+``<root>/chipbench/``, so a later cell, mix, configuration, architecture or
+metric is added as new files and entries only.
 """
 
 from __future__ import annotations
@@ -15,7 +16,8 @@ from pathlib import Path
 from types import ModuleType
 from typing import Dict, List
 
-ROOT = Path(__file__).resolve().parent.parent
+PACKAGE = Path(__file__).resolve().parent
+ROOT = PACKAGE.parent
 
 
 @dataclasses.dataclass(frozen=True)
@@ -37,6 +39,8 @@ class Cell:
     end_to_end: List[Metric]
     per_layer: List[Metric]
     root: Path
+    arch: ModuleType          # arch/<name>.py, by the config's ``reference``
+    reference: ModuleType     # reference/<name>.py, by the same name
 
 
 def _json(path: Path) -> Dict:
@@ -71,26 +75,42 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
         if key in cell and cell[key] != entry[key]:
             raise ValueError(f"{name}: {key} is {cell[key]!r} in its cell "
                              f"file and {entry[key]!r} in BENCHMARK.json")
+    config = _json(data_path(root, "configs", entry["config"]))
+    arch = named_module(root, "arch", config["reference"])
+    reference = named_module(root, "reference", config["reference"])
     e2e = _metrics(bench, "end_to_end", name)
     moved = {m.name for m in e2e}
     per_layer = [m for m in _metrics(bench, "per_layer", name)
                  if m.moves in moved]
     return Cell(
-        name=name, chips=int(entry["chips"]),
-        config=_json(data_path(root, "configs", entry["config"])),
+        name=name, chips=int(entry["chips"]), config=config,
         traffic=_json(data_path(root, "traffic", entry["traffic"])),
         rate_per_s=float(cell["rate_per_s"]),
         limits={k: float(v) for k, v in cell["limits"].items()},
         sample=int(cell.get("sample", 6)),
-        end_to_end=e2e, per_layer=per_layer, root=root)
+        end_to_end=e2e, per_layer=per_layer, root=root, arch=arch,
+        reference=reference)
+
+
+def _load(path: Path, module_name: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(module_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def named_module(root: Path, kind: str, name: str) -> ModuleType:
+    """The module ``<root>/chipbench/<kind>/<name>.py``: this package's own
+    where ``root`` is its checkout, else loaded from the file.  A name
+    with no such file raises, naming the file it looked for."""
+    path = data_path(root, kind, name, ".py")
+    if path.resolve() == PACKAGE / kind / path.name:
+        return importlib.import_module(f"chipbench.{kind}.{name}")
+    return _load(path, f"chipbench_{kind}_{name}")
 
 
 def metric_reader(root: Path, name: str) -> ModuleType:
     """The module ``metrics/<name>.py``; its ``read(record)`` gives the
     metric's value or None where the run holds nothing to read."""
-    path = data_path(root, "metrics", name, ".py")
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return _load(data_path(root, "metrics", name, ".py"),
+                 f"chipbench_metric_{name.replace('.', '_')}")

@@ -40,10 +40,10 @@ def main(argv=None) -> int:
         mix.update(replicas=args.replicas, move_every_s=0)
     devices = chip_devices(int(mix.get("replicas", 1)))
     configure_compile_cache()
-    config, model = cell.config, cell.config["model"]
-    cfg = program.model_config(config)
-    params = program.program_params(
-        weights.generate(model, args.seed, devices[0]), cfg)
+    config, model, arch = cell.config, cell.config["model"], cell.arch
+    cfg = arch.model_config(config)
+    params = program.params(
+        arch, weights.generate(arch, model, args.seed, devices[0]), cfg)
     for rate in (float(r) for r in args.rates.split(",")):
         rec = serving.Recorder()
         note = serving.Annotator(False)

@@ -55,10 +55,12 @@ def make_root(tmp_path: Path, cell: str = "tiny.chat", replicas: int = 1,
               rate: float = 6.0, model=None, sample: int = 8) -> Path:
     """A benchmark root holding only new files: BENCHMARK.json with one
     cell, its configuration, mix and cell file, and copies of the metric
-    readers."""
+    readers, architecture modules and plain references."""
     root = tmp_path / "bench"
     data = root / "chipbench"
-    shutil.copytree(ROOT / "chipbench" / "metrics", data / "metrics")
+    for kind in ("metrics", "arch", "reference"):
+        shutil.copytree(ROOT / "chipbench" / kind, data / kind,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     bench["workloads"] = [{"name": cell, "config": "tiny", "traffic": "mix",
                            "chips": replicas, "why": "CPU test"}]

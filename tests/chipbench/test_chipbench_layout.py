@@ -7,7 +7,7 @@ import re
 import jax
 import pytest
 
-from chipbench import program, run, spec
+from chipbench import arch, run, spec
 from conftest import ROOT, make_root
 from repro.configs import get_config
 
@@ -100,13 +100,23 @@ def test_config_file_is_what_the_program_runs(path):
     for key in config["reduced"]:          # departures, never widths
         assert not re.search(r"(size|_dim|_rank|heads|experts)", key)
         assert key in config["published"]
-    assert program.model_config(config) == get_config(config["registry"])
+    model_config = arch.module(config["reference"]).model_config
+    assert model_config(config) == get_config(config["registry"])
 
 
 def test_a_cell_config_mix_and_metric_added_as_new_files_run(tmp_path):
     """Everything the tiny cell needs lives in a temporary root; a metric
-    added there as one file and one entry is reported."""
+    added there as one file and one entry is reported, and an architecture
+    added there as one module and one reference, named by the
+    configuration, is the one the cell runs."""
     root = make_root(tmp_path, "fresh.cell")
+    data = root / "chipbench"
+    for kind in ("arch", "reference"):
+        (data / kind / "fresh_decoder.py").write_text(
+            (ROOT / "chipbench" / kind / "dense_decoder.py").read_text())
+    path = data / "configs" / "tiny.json"
+    path.write_text(json.dumps(dict(json.loads(path.read_text()),
+                                    reference="fresh_decoder")))
     bench = json.loads((root / "BENCHMARK.json").read_text())
     bench["end_to_end"].append({"name": "served_tokens", "unit": "tokens",
                                 "better": "higher", "bound": 0.05,
@@ -118,6 +128,8 @@ def test_a_cell_config_mix_and_metric_added_as_new_files_run(tmp_path):
         "    return sum(len(r.tokens) for r in run.rec.requests.values())\n")
     cell = spec.load_cell("fresh.cell", root)
     assert cell.config["model"]["hidden_size"] == 128
+    for kind, mod in (("arch", cell.arch), ("reference", cell.reference)):
+        assert mod.__file__ == str(data / kind / "fresh_decoder.py")
     out = run.run_cell(cell, 5, 1.5, False, jax.devices()[:1])
     assert out["correct"]
     assert out["metrics"]["served_tokens"]["value"] > 0
@@ -130,3 +142,26 @@ def test_cell_file_that_disagrees_with_the_benchmark_is_refused(tmp_path):
     path.write_text(json.dumps(dict(json.loads(path.read_text()), chips=4)))
     with pytest.raises(ValueError, match="chips"):
         spec.load_cell("tiny.chat", root)
+
+
+@pytest.mark.parametrize("kind", ["arch", "reference"])
+def test_architecture_without_a_module_is_refused_at_load(tmp_path, kind):
+    root = make_root(tmp_path)
+    path = root / "chipbench" / "configs" / "tiny.json"
+    path.write_text(json.dumps(dict(json.loads(path.read_text()),
+                                    reference="no_such_arch")))
+    if kind == "reference":          # the architecture module alone is there
+        (root / "chipbench" / "arch" / "no_such_arch.py").write_text(
+            (ROOT / "chipbench" / "arch" / "dense_decoder.py").read_text())
+    want = root / "chipbench" / kind / "no_such_arch.py"
+    with pytest.raises(FileNotFoundError, match=str(want)):
+        spec.load_cell("tiny.chat", root)
+
+
+def test_every_architecture_module_gives_what_the_harness_calls():
+    for path in sorted((ROOT / "chipbench" / "arch").glob("[!_]*.py")):
+        mod = arch.module(path.stem)
+        for name in ("spec", "model_config", "program_params",
+                     "parameter_count", "step_work", "session_bytes"):
+            assert callable(getattr(mod, name)), (path.stem, name)
+        assert (ROOT / "chipbench" / "reference" / path.name).is_file()
